@@ -265,21 +265,8 @@ def assert_bounded_compiles(server) -> None:
     ``_cache_size`` sums): a wrapper that silently recompiled for a
     shape/dtype the bucket key didn't capture now trips this assert
     instead of hiding behind a one-count-per-wrapper scheme.
-
-    On a jax build without the private counter API
-    (``COMPILE_COUNTER_EXACT`` False) the counters degrade to one per
-    wrapper — a *lower* bound on real executables, so the ladder check
-    still holds but can no longer catch silent recompiles. That
-    downgrade is announced rather than silent.
     """
     from repro.serve import ExpertEngine
-    from repro.serve.core import COMPILE_COUNTER_EXACT
-    if not COMPILE_COUNTER_EXACT:
-        print("# WARNING: jit._cache_size() unavailable on this jax "
-              "build; compile counters fall back to one per wrapper "
-              "(>= semantics: a lower bound on real executables). The "
-              "ladder bound below still holds, but silent per-wrapper "
-              "recompiles cannot be detected.", flush=True)
     cores = [s.bank.core for s in server.scheduler.shards if s.banked]
     cores += [b.core for b in (server.registry[e].backend
                                for e in range(len(server.registry)))
@@ -567,12 +554,6 @@ def run_hub_bench(args) -> None:
           f"after the measured run", flush=True)
     # the ISSUE's acceptance criteria, asserted in-process so CI only
     # has to check the exit code
-    from repro.serve.core import COMPILE_COUNTER_EXACT
-    if not COMPILE_COUNTER_EXACT:
-        print("# WARNING: inexact compile counters (no _cache_size): "
-              "the steady-state check degrades to wrapper-count "
-              "equality and cannot see per-wrapper recompiles.",
-              flush=True)
     assert st.evictions > 0, "no evictions: catalog fits the slots?"
     assert jit_end == jit_warm, (
         f"steady-state recompiles: {jit_warm} executables post-warmup "
@@ -1162,6 +1143,8 @@ def main():
                          "for the banked placement path); 0 = leave the "
                          "platform's real device count")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    print(f"# compile cache: {enable_compile_cache()}", flush=True)
     if args.requests < 1:
         ap.error("--requests must be >= 1")
     if args.rate <= 0:
@@ -1232,13 +1215,10 @@ def main():
                          args.requests, args.rate, args.seed)
         results.append(r)
         print(_csv_row(r, args), flush=True)
-    from repro.serve.core import COMPILE_COUNTER_EXACT
     pf = total_prefill_tokens(server)
     totals = {
         # compile counts are *real* XLA executables (per-wrapper
-        # _cache_size sums), not jit-wrapper creations — unless this
-        # jax build lacks the API (then one-per-wrapper, flagged here)
-        "compile_counter_exact": COMPILE_COUNTER_EXACT,
+        # _cache_size sums), not jit-wrapper creations
         "prefill_compiles": total_prefill_compiles(server),
         "decode_compiles": total_decode_compiles(server),
         "host_blocks": total_host_blocks(server),

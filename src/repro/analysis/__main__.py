@@ -68,13 +68,14 @@ def _run_hlo_inprocess() -> List[Violation]:
 
 def _run_hlo_subprocess() -> List[Violation]:
     """Re-exec the hlo pass with the 8-device CPU environment forced
-    before jax can initialise in the child."""
+    before jax can initialise in the child. The child never takes the
+    TPU: a chip belongs to one process, and the parent may hold it."""
     env = dict(os.environ)
     flags = env.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         env["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=8").strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     src = os.path.join(REPO_ROOT, "src")
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (src, env.get("PYTHONPATH")) if p)

@@ -248,7 +248,6 @@ def run() -> List[Violation]:
     import warnings as _w
     import jax
     import jax.numpy as jnp
-    from ..serve.core import COMPILE_COUNTER_EXACT
 
     _require_devices(8)
     out: List[Violation] = []
@@ -269,29 +268,24 @@ def run() -> List[Violation]:
     got_s = core.stats.suffix_compiles
     got_d = core.stats.decode_compiles
     got_i = hub.install_compiles
-    cmp_name = "==" if COMPILE_COUNTER_EXACT else ">="
-
-    def bad(got, want):
-        return (got != want) if COMPILE_COUNTER_EXACT else (got < want)
-
-    if bad(got_p, bounds["prefill"]):
+    if got_p != bounds["prefill"]:
         out.append(Violation(
             "H004", _CORE_PATH, 0, "prefill_ladder",
             f"prefill executables after full warmup: {got_p}, declared "
-            f"bound {cmp_name} {bounds['prefill']} "
+            f"bound == {bounds['prefill']} "
             f"(executable_bounds: buckets <= chunk_len x batch_buckets)"))
-    if bad(got_s, bounds["suffix"]):
+    if got_s != bounds["suffix"]:
         out.append(Violation(
             "H004", _CORE_PATH, 0, "suffix_ladder",
             f"suffix executables after full warmup: {got_s}, declared "
-            f"bound {cmp_name} {bounds['suffix']} "
+            f"bound == {bounds['suffix']} "
             f"(executable_bounds: chunk indices x batch_buckets)"))
-    if bad(got_d, bounds["decode"]):
+    if got_d != bounds["decode"]:
         out.append(Violation(
             "H004", _CORE_PATH, 0, "decode_ladder",
             f"decode executables after full warmup: {got_d}, declared "
-            f"bound {cmp_name} {bounds['decode']} (batch_buckets)"))
-    if COMPILE_COUNTER_EXACT and got_i != 1:
+            f"bound == {bounds['decode']} (batch_buckets)"))
+    if got_i != 1:
         out.append(Violation(
             "H004", _HUB_PATH, 0, "hub_install",
             f"hub install executables: {got_i}, expected exactly 1 "
@@ -369,17 +363,17 @@ def run() -> List[Violation]:
     sbounds = score.executable_bounds()
     got_v = score.stats.verify_compiles
     got_fd = score.stats.decode_compiles
-    if bad(got_v, sbounds["verify"]):
+    if got_v != sbounds["verify"]:
         out.append(Violation(
             "H004", _CORE_PATH, 0, "verify_ladder",
             f"verify executables after the speculative grid: {got_v}, "
-            f"declared bound {cmp_name} {sbounds['verify']} "
+            f"declared bound == {sbounds['verify']} "
             "(executable_bounds: batch_buckets x one engine-fixed k)"))
-    if bad(got_fd, sbounds["decode"]):
+    if got_fd != sbounds["decode"]:
         out.append(Violation(
             "H004", _CORE_PATH, 0, "spec_fallback_decode_ladder",
             f"decode executables after gate-blocked (wrap-risk) waves: "
-            f"{got_fd}, declared bound {cmp_name} {sbounds['decode']} "
+            f"{got_fd}, declared bound == {sbounds['decode']} "
             "— speculation must not mint extra decode variants"))
     if score.stats.spec_fallback_waves == 0:
         out.append(Violation(

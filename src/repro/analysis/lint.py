@@ -15,9 +15,8 @@ L002  Python control flow (``if``/``while``/``assert``) testing a
       traced value — a ConcretizationTypeError at trace time, or a
       per-call host block under ``jax.disable_jit``.
 L003  use of the private jit ``_cache_size`` API anywhere but the one
-      guarded helper in ``serve/core.py`` (``_wrapper_compiles``); the
-      API is version-probed there (``COMPILE_COUNTER_EXACT``) and raw
-      call sites would crash on jax versions that dropped it.
+      helper in ``serve/core.py`` (``_wrapper_compiles``): a jax release
+      that changes or drops the API then breaks in one place.
 L004  a ``time.time()``/``perf_counter()`` timed region that dispatches
       device work but never blocks on it (``jax.block_until_ready``,
       ``device_get``, ``np.asarray`` ...): async dispatch means such a

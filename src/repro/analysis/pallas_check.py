@@ -1,13 +1,14 @@
 """Pallas kernel validator (rules P001-P004): BlockSpec geometry
 checked on a CPU-only runner.
 
-Every serving kernel ships with ``interpret=True`` so CI can execute it
-without a TPU — but interpret mode checks *none* of the Mosaic lowering
-constraints, so a BlockSpec whose index map walks off the operand, a
-block that doesn't divide its array, or a scratch buffer in an illegal
-memory space all pass CI green and explode on first real-TPU run
-(ROADMAP: "Real Mosaic path"). This pass closes the CPU-checkable half
-of that gap statically:
+On the CPU every serving kernel runs in the Pallas interpreter, so CI
+can execute it without a TPU — but interpret mode checks *none* of the
+Mosaic lowering constraints, so a BlockSpec whose index map walks off
+the operand, a block that doesn't divide its array, or a scratch buffer
+in an illegal memory space all pass CI green and explode on the first
+real-TPU run. This pass closes the CPU-checkable half of that gap
+statically (``tests/test_tpu_compile.py`` closes the rest by compiling
+the routing kernels for a described v5e chip):
 
   P001  block-shape divisibility: every BlockSpec dim must divide its
         operand dim (the repo's kernels are written no-padding; a

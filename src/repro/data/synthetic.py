@@ -13,6 +13,7 @@ All generators return (x (N, raw_dim...), y (N,)) in numpy; preprocessing
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Callable, Dict, List, Tuple
 
 import numpy as np
@@ -192,6 +193,9 @@ def generate(name: str, n: int | None = None, seed: int = 0):
     """Generate dataset ``name``; n=None uses the paper's sample count."""
     spec = SPECS[name]
     n = n if n is not None else spec.n_samples
-    x, y = _GENERATORS[name](spec, n, seed + hash(name) % 10_000)
+    # crc32, not hash(): str hashes are salted per process, and the
+    # data must be the same in every process for one seed
+    x, y = _GENERATORS[name](spec, n,
+                             seed + zlib.crc32(name.encode()) % 10_000)
     perm = np.random.default_rng(seed).permutation(len(x))
     return x[perm], y[perm]

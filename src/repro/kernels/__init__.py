@@ -6,9 +6,10 @@
   wkv_step.py         — fused RWKV6 decode step (state + output, one pass)
 
 Each kernel ships with a pure-jnp oracle in ref.py and a jitted public
-wrapper in ops.py; kernels run with interpret=True on CPU (validated
-against the oracles in tests/test_kernels.py) and compile via Mosaic on
-real TPUs.
+wrapper in ops.py. The backend picks the mode (mode.py): the Pallas
+interpreter on the CPU, where tests/test_kernels.py checks every kernel
+against its oracle, and Mosaic on a TPU; tests/test_tpu_compile.py
+compiles the routing kernels for a described v5e chip.
 """
 from . import ops, ref
 

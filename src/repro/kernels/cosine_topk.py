@@ -8,10 +8,13 @@ so downstream argmax is safe. Grid over sample tiles; the centroid matrix
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .mode import resolve_interpret
 
 
 def _kernel(z_ref, c_ref, mask_ref, out_ref, *, eps: float):
@@ -20,12 +23,14 @@ def _kernel(z_ref, c_ref, mask_ref, out_ref, *, eps: float):
     mask = mask_ref[...]                # (1, M)
     zn = z * jax.lax.rsqrt(jnp.sum(z * z, -1, keepdims=True) + eps)
     cn = c * jax.lax.rsqrt(jnp.sum(c * c, -1, keepdims=True) + eps)
-    sim = zn @ cn.T                     # (bm, M)
+    sim = jnp.dot(zn, cn.T,             # (bm, M); f32, as expert_score
+                  precision=jax.lax.Precision.HIGHEST)
     out_ref[...] = jnp.where(mask > 0, sim, -jnp.inf)
 
 
 def cosine_scores_pallas(z, centroids, mask, *, block_m: int = 128,
-                         eps: float = 1e-12, interpret: bool = True):
+                         eps: float = 1e-12,
+                         interpret: Optional[bool] = None):
     """z: (B, h); centroids: (M, h); mask: (M,). Returns (B, M) cosine
     similarity with masked classes = -inf."""
     B, h = z.shape
@@ -42,5 +47,5 @@ def cosine_scores_pallas(z, centroids, mask, *, block_m: int = 128,
         ],
         out_specs=pl.BlockSpec((bm, M), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, M), z.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(z, centroids, mask[None, :].astype(z.dtype))

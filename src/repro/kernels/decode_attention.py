@@ -26,11 +26,14 @@ The pure-jnp oracle for both is ``repro.models.attention.attention``
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .mode import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -74,7 +77,8 @@ def _kernel(qpos_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
 
 
 def decode_attention_pallas(q, k, v, q_pos, kv_pos, *, window: int = 0,
-                            block_s: int = 512, interpret: bool = True):
+                            block_s: int = 512,
+                            interpret: Optional[bool] = None):
     """q: (B, H, dh); k, v: (B, S, KV, dh); q_pos: () int32;
     kv_pos: (S,) int32 (-1 = empty). Returns (B, H, dh)."""
     B, H, dh = q.shape
@@ -103,7 +107,7 @@ def decode_attention_pallas(q, k, v, q_pos, kv_pos, *, window: int = 0,
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, dh), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q_pos.reshape(1).astype(jnp.int32),
       qg, kt.reshape(B * KV, S, dh), vt.reshape(B * KV, S, dh),
       kv_pos[None, :].astype(jnp.int32))
@@ -122,7 +126,7 @@ def _paged_kernel(tbl_ref, qpos_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
 
 def paged_decode_attention_pallas(q, k_pages, v_pages, table, q_pos,
                                   kv_pos, *, window: int = 0,
-                                  interpret: bool = True):
+                                  interpret: Optional[bool] = None):
     """Flash-decode through a per-row page table.
 
     q: (B, H, dh); k_pages, v_pages: (P1, page, KV, dh) physical pool
@@ -167,7 +171,7 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, table, q_pos,
         functools.partial(_paged_kernel, window=window, n_blocks=nlp),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, dh), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(table.reshape(-1).astype(jnp.int32),
       q_pos.reshape(1).astype(jnp.int32),
       qg, kp, vp, kv_pos[None, :].astype(jnp.int32))

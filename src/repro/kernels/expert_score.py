@@ -16,10 +16,13 @@ VMEM for the whole sample tile, so reconstructions never touch HBM.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .mode import resolve_interpret
 
 LANE = 128
 
@@ -29,33 +32,43 @@ def pad_to_lane(d: int) -> int:
 
 
 def _kernel(x_ref, w1_ref, b1_ref, w2_ref, b2_ref, out_ref, *, d_real: int):
+    # f32 matmuls at HIGHEST: Mosaic's default runs them as bf16 passes,
+    # which moved scores by 1.6% on a v5e — enough to flip close routes
+    dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST)
     x = x_ref[...]  # (bm, Dp)
-    h = jnp.maximum(x @ w1_ref[0] + b1_ref[0], 0.0)  # (bm, H)
-    xhat = h @ w2_ref[0] + b2_ref[0]  # (bm, Dp)
+    h = jnp.maximum(dot(x, w1_ref[0]) + b1_ref[0], 0.0)  # (bm, H)
+    xhat = dot(h, w2_ref[0]) + b2_ref[0]  # (bm, Dp)
     d = xhat - x
-    out_ref[:, 0] = jnp.sum(d * d, axis=-1) / d_real
+    out_ref[0] = jnp.sum(d * d, axis=-1, keepdims=True) / d_real  # (bm, 1)
 
 
 def expert_score_pallas(x, w1, b1, w2, b2, *, d_real: int, block_m: int = 128,
-                        interpret: bool = True):
+                        interpret: Optional[bool] = None):
     """x: (B, Dp) f32; w1: (K, Dp, H); b1: (K, H); w2: (K, H, Dp);
-    b2: (K, Dp). Returns (B, K) per-sample MSE. Dp must be lane-padded."""
+    b2: (K, Dp). Returns (B, K) per-sample MSE. Dp must be lane-padded.
+
+    Mosaic tiles the last two dims of every block by (8, 128) unless a
+    block spans them whole. So the biases ride as (K, 1, n) and the
+    scores come out expert-major as (K, B, 1): each block's trailing
+    dims are then either whole or the (bm, Dp)/(bm, H) tiles, and bm
+    is a multiple of 8 or all of B."""
     B, Dp = x.shape
     K, _, H = w1.shape
     bm = min(block_m, B)
     assert B % bm == 0, (B, bm)
     grid = (B // bm, K)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_kernel, d_real=d_real),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, Dp), lambda i, k: (i, 0)),
             pl.BlockSpec((1, Dp, H), lambda i, k: (k, 0, 0)),
-            pl.BlockSpec((1, H), lambda i, k: (k, 0)),
+            pl.BlockSpec((1, 1, H), lambda i, k: (k, 0, 0)),
             pl.BlockSpec((1, H, Dp), lambda i, k: (k, 0, 0)),
-            pl.BlockSpec((1, Dp), lambda i, k: (k, 0)),
+            pl.BlockSpec((1, 1, Dp), lambda i, k: (k, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((bm, 1), lambda i, k: (i, k)),
-        out_shape=jax.ShapeDtypeStruct((B, K), x.dtype),
-        interpret=interpret,
-    )(x, w1, b1, w2, b2)
+        out_specs=pl.BlockSpec((1, bm, 1), lambda i, k: (k, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((K, B, 1), x.dtype),
+        interpret=resolve_interpret(interpret),
+    )(x, w1, b1[:, None, :], w2, b2[:, None, :])
+    return out[:, :, 0].T

@@ -3,12 +3,14 @@
 ``expert_score(bank_params, x)`` is a drop-in for
 ``repro.core.autoencoder.bank_scores``: it folds each AE's eval-mode
 BatchNorm into the encoder weights, lane-pads 784 -> 896, and calls the
-fused kernel. ``interpret=True`` everywhere in this container (CPU);
-on a real TPU pass ``interpret=False`` for the Mosaic path.
+fused kernel. Every wrapper's ``interpret=None`` leaves the mode to the
+backend (``repro.kernels.mode``): interpreted on the CPU, Mosaic on a
+TPU.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -42,7 +44,7 @@ def fold_bank(bank_params, bank_states, eps: float = 1e-5):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_m"))
-def expert_score_folded(folded, x, *, interpret: bool = True,
+def expert_score_folded(folded, x, *, interpret: Optional[bool] = None,
                         block_m: int = 128):
     """x: (B, 784) -> (B, K) reconstruction MSE via the fused kernel."""
     B, D = x.shape  # D = real (unpadded) feature dim — static at trace time
@@ -57,7 +59,8 @@ def expert_score_folded(folded, x, *, interpret: bool = True,
                                interpret=interpret)
 
 
-def expert_score(bank_params, x, bank_states=None, *, interpret: bool = True):
+def expert_score(bank_params, x, bank_states=None, *,
+                 interpret: Optional[bool] = None):
     """Convenience entry used by MatcherConfig(use_kernel=True)."""
     if bank_states is None:  # identity BN stats
         K, _, H = bank_params["w_enc"].shape
@@ -68,7 +71,7 @@ def expert_score(bank_params, x, bank_states=None, *, interpret: bool = True):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def cosine_scores(z, centroids, mask, *, interpret: bool = True):
+def cosine_scores(z, centroids, mask, *, interpret: Optional[bool] = None):
     B = z.shape[0]
     bm = 128
     while B % bm:
@@ -80,7 +83,8 @@ def cosine_scores(z, centroids, mask, *, interpret: bool = True):
 @functools.partial(jax.jit, static_argnames=("window", "interpret",
                                              "block_s"))
 def decode_attention(q, k, v, q_pos, kv_pos, *, window: int = 0,
-                     block_s: int = 512, interpret: bool = True):
+                     block_s: int = 512,
+                     interpret: Optional[bool] = None):
     S = k.shape[1]
     bs = min(block_s, S)
     while S % bs:
@@ -91,7 +95,8 @@ def decode_attention(q, k, v, q_pos, kv_pos, *, window: int = 0,
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_decode_attention(q, k_pages, v_pages, table, q_pos, kv_pos, *,
-                           window: int = 0, interpret: bool = True):
+                           window: int = 0,
+                           interpret: Optional[bool] = None):
     """Flash-decode gathering K/V through a per-row page table (the
     paged-KV serving layout). Block size is the page size — the pool's
     physical granularity IS the kernel's VMEM tile."""
@@ -101,6 +106,7 @@ def paged_decode_attention(q, k_pages, v_pages, table, q_pos, kv_pos, *,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def wkv_decode_step(r, k, v, logw, u, state, *, interpret: bool = True):
+def wkv_decode_step(r, k, v, logw, u, state, *,
+                    interpret: Optional[bool] = None):
     """Fused RWKV6 decode step (output + state update in one VMEM pass)."""
     return wkv_step_pallas(r, k, v, logw, u, state, interpret=interpret)

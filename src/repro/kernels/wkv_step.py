@@ -13,9 +13,13 @@ per token vs the two-pass jnp formulation. Oracle: repro.models.rwkv6.wkv_step.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .mode import resolve_interpret
 
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s_ref, o_ref, s_out_ref):
@@ -33,7 +37,8 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s_ref, o_ref, s_out_ref):
     s_out_ref[0] = (jnp.exp(w).T * S + kv).astype(s_out_ref.dtype)
 
 
-def wkv_step_pallas(r, k, v, logw, u, state, *, interpret: bool = True):
+def wkv_step_pallas(r, k, v, logw, u, state, *,
+                    interpret: Optional[bool] = None):
     """r/k/v/logw: (B, H, P); u: (H, P); state: (B, H, P, P) f32.
     Returns (o (B, H, P) f32, new_state (B, H, P, P) f32)."""
     B, H, P = r.shape
@@ -60,7 +65,7 @@ def wkv_step_pallas(r, k, v, logw, u, state, *, interpret: bool = True):
             jax.ShapeDtypeStruct((B, H, 1, P), jnp.float32),
             jax.ShapeDtypeStruct((B * H, P, P), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rs, ks, vs, ws, u.reshape(H, 1, P),
       state.reshape(B * H, P, P).astype(jnp.float32))
     return o.reshape(B, H, P), s_new.reshape(B, H, P, P)

@@ -21,29 +21,22 @@ HW = {
 }
 
 
-def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` across JAX versions.
-
-    ``jax.sharding.AxisType`` only exists from JAX 0.5; the pinned 0.4.37
-    predates it (all axes are implicitly Auto there, so omitting
-    ``axis_types`` is semantically identical).
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules
+    annotate inputs and leave propagation to GSPMD."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """1x1 mesh for CPU smoke runs (everything replicated)."""
-    return compat_make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def make_expert_mesh():
@@ -57,7 +50,7 @@ def make_expert_mesh():
     with ``JAX_PLATFORMS=cpu``); on a TPU slice the real chips show up
     here instead.
     """
-    return compat_make_mesh((len(jax.devices()),), ("expert",))
+    return make_mesh((len(jax.devices()),), ("expert",))
 
 
 def mesh_devices_required(multi_pod: bool) -> int:

@@ -28,6 +28,7 @@ from ..data import load_benchmark
 from ..models import build_model
 from ..obs import Tracer
 from ..serve import ExpertEngine, ExpertHub, Request, RoutedServer
+from .compile_cache import enable_compile_cache
 
 
 def main():
@@ -60,6 +61,7 @@ def main():
                          "(open in chrome://tracing or Perfetto), plus "
                          "a greppable JSONL sibling at OUT + 'l'")
     args = ap.parse_args()
+    print(f"compile cache: {enable_compile_cache()}")
 
     t0 = time.time()
     bench = load_benchmark(n_per_dataset=args.n_per_dataset)

@@ -205,16 +205,24 @@ class DecoderLM(BaseModel):
         return x, aux, kvs
 
     # ------------------------------------------------------------------
-    def loss(self, params, batch):
+    def _forward(self, params, batch):
         cfg = self.cfg
         x = self._embed(params, batch)
         S = x.shape[1]
         positions = jnp.arange(S)
         x, aux, _ = self._run_layers(params, x, positions)
         x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
-        if cfg.n_stub_embeds:  # loss only on text positions
+        if cfg.n_stub_embeds:  # logits only at text positions
             x = x[:, cfg.n_stub_embeds:]
-        logits = self._unembed(params, x)
+        return self._unembed(params, x), aux
+
+    def logits(self, params, batch):
+        """Teacher-forced logits at every text position: (B, S, V)."""
+        return self._forward(params, batch)[0]
+
+    def loss(self, params, batch):
+        cfg = self.cfg
+        logits, aux = self._forward(params, batch)
         ce = softmax_xent(logits, batch["labels"])
         total = ce + 0.01 * aux / max(cfg.n_layers, 1)
         return total, {"ce": ce, "aux": aux}

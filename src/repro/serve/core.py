@@ -99,36 +99,16 @@ def bucket_for(n: int, buckets: Sequence[int]) -> int:
 # ---------------------------------------------------------------------------
 
 
-def _probe_cache_size() -> bool:
-    try:
-        return callable(getattr(jax.jit(lambda: 0), "_cache_size"))
-    except Exception:
-        return False
-
-
-# ``_cache_size`` is a private jax API (present on the pinned 0.4.37);
-# probe once at import so a build without it degrades *visibly* — the
-# compile counters revert to one-count-per-wrapper and tests/tools that
-# need exact semantics check this flag instead of silently passing.
-COMPILE_COUNTER_EXACT = _probe_cache_size()
-
-
 def _wrapper_compiles(fn) -> int:
     """Real XLA executables behind one jit wrapper.
 
     ``_cache_size()`` is the C++ pjit cache entry count — it grows when
     a wrapper recompiles for a signature the Python-side bucket key
     didn't capture (cache dtype/shape drift), which a
-    one-count-per-wrapper scheme silently missed. On jax builds without
-    the API (``COMPILE_COUNTER_EXACT`` False) this falls back to 1 per
-    wrapper — the pre-refactor upper-bound semantics.
+    one-count-per-wrapper scheme silently missed. It is a private jax
+    API: this helper is its one call site (lint rule L003).
     """
-    if not COMPILE_COUNTER_EXACT:
-        return 1
-    try:
-        return int(fn._cache_size())
-    except TypeError:
-        return 1
+    return int(fn._cache_size())
 
 
 class EngineStats:

@@ -29,6 +29,7 @@ init); on a TPU slice the same code places banks across real chips.
 from __future__ import annotations
 
 import dataclasses
+import gc
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
@@ -287,6 +288,13 @@ def plan_placement(registry, *, mesh: Optional[Mesh] = None,
         for local, e in enumerate(experts):
             registry[e].backend = BankMember(bank, local)
             shard_of[e] = sid
+    if shards:
+        # each replaced engine's core sits in reference cycles (its jit
+        # closures and stats point back at it), so dropping the last
+        # reference frees nothing: collect now, or their params and KV
+        # pools stay on the device beside the bank's stacked copy
+        del backend, engines
+        gc.collect()
     for e in range(len(registry)):
         if e in shard_of:
             continue

@@ -9,7 +9,8 @@ the Pallas ``cosine_scores`` kernel dead. This front-end:
   * runs fine assignment per routed-expert *group* — each sample is
     encoded only under its own expert, and the group's (z, centroids,
     mask) triple goes through the fused ``cosine_scores`` kernel
-    (interpret mode on CPU, Mosaic on TPU);
+    (Mosaic on a TPU, the Pallas interpreter on the CPU — chosen by
+    the backend in ``repro.kernels.mode``);
   * memoizes routing decisions per client fingerprint in an LRU: clients
     in the paper's setting re-query with the same dataset fingerprint,
     so repeat routes cost a dict lookup instead of K AE forwards.
@@ -91,13 +92,11 @@ class Router:
 
     def __init__(self, matcher: ExpertMatcher, *, cache_size: int = 4096,
                  use_fine_kernel: bool = True, max_rows: int = 256,
-                 interpret: bool = True,
                  shard_of: Optional[Dict[int, int]] = None):
         self.matcher = matcher
         self.shard_of = dict(shard_of) if shard_of is not None else None
         self.use_fine_kernel = use_fine_kernel and \
             matcher.centroids is not None
-        self.interpret = interpret
         self.row_buckets = make_buckets(1, max_rows)
         self._lru: "collections.OrderedDict[bytes, tuple]" = \
             collections.OrderedDict()
@@ -144,8 +143,7 @@ class Router:
             xg, n = self._pad_rows(x[rows])
             z = self._encode_at(xg, jnp.int32(e))
             sim = kops.cosine_scores(z, m.centroids[int(e)],
-                                     m.centroid_mask[int(e)],
-                                     interpret=self.interpret)
+                                     m.centroid_mask[int(e)])
             fine[rows] = np.asarray(jnp.argmax(sim, axis=-1))[:n]
             self.stats["score_calls"] += 1
         return fine

@@ -302,7 +302,6 @@ def test_chunked_executable_bounds_exact(shared_model):
     executables ``executable_bounds`` predicts — monolithic prefills
     only up to chunk_len, one suffix executable per (batch bucket,
     chunk index) — and re-running the same traffic must mint none."""
-    from repro.serve.core import COMPILE_COUNTER_EXACT
     model, params = shared_model
     eng = ExpertEngine(model, params[0], max_len=64, kv_layout="paged",
                        batch_buckets=(1, 2), chunk_len=16)
@@ -326,10 +325,9 @@ def test_chunked_executable_bounds_exact(shared_model):
 
     drive()
     st = eng.stats
-    if COMPILE_COUNTER_EXACT:
-        assert st.prefill_compiles == bounds["prefill"], st
-        assert st.suffix_compiles == bounds["suffix"], st
-        assert st.decode_compiles == bounds["decode"], st
+    assert st.prefill_compiles == bounds["prefill"], st
+    assert st.suffix_compiles == bounds["suffix"], st
+    assert st.decode_compiles == bounds["decode"], st
     entries = st.jit_cache_entries
     assert entries <= sum(bounds.values())
     drive()                     # steady state: zero recompiles

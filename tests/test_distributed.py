@@ -22,7 +22,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.optim import constant_lr
 from repro.sharding import mesh_context
@@ -30,7 +30,7 @@ from repro.sharding.rules import batch_spec, param_specs
 from repro.train.loop import init_train_state, make_train_step
 
 assert len(jax.devices()) == 8, jax.devices()
-mesh = compat_make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 
 cfg = get_config("llama3.2-1b").reduced(
     n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, d_ff=128,

@@ -1,5 +1,5 @@
 """Pallas kernel validation: shape/dtype sweeps vs pure-jnp oracles
-(interpret=True executes the kernel body on CPU)."""
+(the CPU backend runs every kernel body in the Pallas interpreter)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -121,7 +121,7 @@ def test_paged_decode_attention_parity(B, H, KV, dh, page, nlp, win,
     """Paged kernel == ring kernel on the gathered dense view == jnp
     reference, through a scrambled page table with shared pages between
     rows and trash-backed (never-written) logical tail pages — the
-    interpret=True Pallas path the serving kernels rely on."""
+    kernel body the serving path would run, here in the interpreter."""
     from repro.kernels.decode_attention import paged_decode_attention_pallas
     from repro.models.attention import paged_gather
     C = nlp * page
@@ -257,3 +257,19 @@ def test_wkv_step_chain_matches_scan(dtype):
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(S), np.asarray(S_scan),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("backend,want", [
+    ("cpu", True), ("tpu", False), ("gpu", None)])
+def test_kernel_mode_follows_the_backend(monkeypatch, backend, want):
+    """One place picks a kernel's mode: the interpreter on the CPU,
+    Mosaic on a TPU, an error anywhere else; an explicit mode wins."""
+    from repro.kernels.mode import resolve_interpret
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if want is None:
+        with pytest.raises(RuntimeError, match="no Pallas kernel path"):
+            resolve_interpret(None)
+    else:
+        assert resolve_interpret(None) is want
+    assert resolve_interpret(True) is True
+    assert resolve_interpret(False) is False

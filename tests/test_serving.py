@@ -1,6 +1,9 @@
 """Serving subsystem tests: scheduler/engine/router behaviour under
 mixed-shape traffic, banked placement equivalence, plus
 kernel-vs-reference routing parity."""
+import gc
+import weakref
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -106,10 +109,7 @@ def test_compile_counters_count_executables_not_wrappers():
     XLA executables (per-wrapper _cache_size sums), not jit-wrapper
     creations: a wrapper that exists but never ran holds no executable,
     and a silently recompiling wrapper would count per compile."""
-    from repro.serve.core import COMPILE_COUNTER_EXACT, _wrapper_compiles
-    if not COMPILE_COUNTER_EXACT:
-        pytest.skip("this jax build lacks jit._cache_size(); counters "
-                    "degrade to one-per-wrapper (flagged, not silent)")
+    from repro.serve.core import _wrapper_compiles
     eng = _engine(seed=14, max_len=32)
     # wrapper created but never called -> no executable yet (the old
     # counter charged a compile at wrapper creation)
@@ -471,6 +471,20 @@ def test_plan_placement_banks_homogeneous_experts(matcher):
     assert isinstance(reg[2].backend, ExpertEngine)
     bank = banked[0].bank
     assert isinstance(bank, BankedEngine) and bank.n_experts == 2
+
+
+def test_plan_placement_frees_replaced_engines(matcher):
+    """The engines a bank replaces hold device params and KV buffers in
+    reference cycles: plan_placement must free them itself, not leave
+    them beside the bank's stacked copy until a later cyclic GC."""
+    _, reg = _registries(matcher)
+    cores = [weakref.ref(reg[e].backend.core) for e in range(len(reg))]
+    gc.disable()                    # only plan_placement may collect
+    try:
+        plan_placement(reg)
+        assert [c() for c in cores] == [None] * len(cores)
+    finally:
+        gc.enable()
 
 
 def test_dispatch_moe_experts_stay_singleton(matcher):

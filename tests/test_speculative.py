@@ -21,9 +21,9 @@ suite is the proof:
     stays token-identical; a ``PagePoolExhausted`` admission rolls
     back transactionally;
   * executable budgets: ``executable_bounds()`` grows exactly one
-    ``verify`` family, post-warmup compile counts are asserted exactly
-    (under ``COMPILE_COUNTER_EXACT``), and the L006 lint extension
-    blesses only bucket-derived ``_verify_fn`` shape arguments.
+    ``verify`` family, post-warmup compile counts are asserted exactly,
+    and the L006 lint extension blesses only bucket-derived
+    ``_verify_fn`` shape arguments.
 
 Property-style grids sample through ``tests/_prop.py`` (see its module
 docstring): the container has no ``hypothesis``, so grids are fixed
@@ -39,7 +39,6 @@ from repro.analysis import lint
 from repro.configs import get_config
 from repro.models import build_model
 from repro.serve import (BankedEngine, ExpertEngine, PagePoolExhausted)
-from repro.serve.core import COMPILE_COUNTER_EXACT
 
 MAX_LEN = 32
 
@@ -293,8 +292,6 @@ def test_executable_bounds_verify_family(tiny):
     assert plain.core.executable_bounds()["verify"] == 0
 
 
-@pytest.mark.skipif(not COMPILE_COUNTER_EXACT,
-                    reason="needs the pjit _cache_size probe")
 def test_spec_compile_counts_exact(tiny):
     """Exact post-warmup executable census: a speculative wave mints
     one prefill and one verify executable — no decode — and repeat
