@@ -1,0 +1,9 @@
+"""Device: share of the profiled seconds in which no operation ran on
+the chip (one minus the union of device operation intervals)."""
+
+
+def read(run):
+    t = run.trace
+    if t is None or not t.window_s:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)

@@ -1,0 +1,37 @@
+"""Model step: the engine's step programs' device time against the
+least time the chip could take for their work in the profiled seconds.
+Each prefill dispatch reads every dispatched expert's layers and head
+once and writes the keys and values of its rows' prompts; each decode
+dispatch reads the same weights and the cached keys and values its
+rows attend over (``bench.flops``). The least time of each phase is the
+larger of its bytes over bandwidth and its operations over peak, and
+the two phases' least times add. Dispatches and rows come from the
+engines' counters over the profiled seconds, decoded tokens from
+``View.decoded_in_trace``, the device time from the trace's step
+programs (``xtrace.STEP_MODULES``)."""
+from bench import flops
+
+
+def read(run):
+    t, c = run.trace, run.trace_counters
+    if t is None or c is None or not run.served:
+        return None
+    dev = t.seconds.get("step", 0.0)
+    if not dev:
+        return None
+    a, e = run.arch, c["engine"]
+    bw, peak = run.peaks["hbm_bytes_per_s"], run.peaks["peak_flops_bf16"]
+    weights = run.experts_per_dispatch * flops.decode_streamed_bytes(a)
+    kv = flops.kv_bytes_per_token(a)
+    pre, keys = flops.served_means(a, run.served)
+    prompt = sum(s.prompt_len for s in run.served) / len(run.served)
+    rows = e.get("rows_served", 0)
+    decoded = run.decoded_in_trace()
+    least = (max((e.get("prefill_calls", 0) * weights
+                  + rows * prompt * kv) / bw, rows * pre / peak)
+             + max((e.get("decode_steps", 0) * weights
+                    + decoded * keys * kv) / bw,
+                   decoded * flops.decode_flops(a, keys) / peak))
+    if not least:
+        return None
+    return 100.0 * least / dev
