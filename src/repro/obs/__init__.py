@@ -11,7 +11,11 @@ layer without dragging a backend in):
     device work uses ``begin_device``/``end_device`` pairs that close
     only at the engine's existing harvest sync points, so tracing adds
     **zero** new host blocks by construction (``EngineStats.host_blocks``
-    is asserted identical with tracing on and off). Export is Chrome
+    is asserted identical with tracing on and off). Each record carries
+    an ``id`` and the ``parent`` span open on its thread, and an enabled
+    tracer enters a ``jax.profiler.TraceAnnotation`` per span (JAX is
+    imported on the first span, never at import), so program spans share
+    the profiler's clock with the device. Export is Chrome
     ``trace_event`` JSON (load in ``chrome://tracing`` / Perfetto) or a
     greppable JSONL stream.
 

@@ -20,28 +20,59 @@ Design constraints, in order:
    enabling toggles — so stats stay populated when tracing is off.
 
 3. **No dependencies.** Pure stdlib; importable from the analysis layer
-   and from tests without jax.
+   and from tests without jax. An enabled tracer imports
+   ``jax.profiler`` on its first span and, where JAX is missing, keeps
+   no profiler bridge.
+
+4. **One clock with the device.** On an enabled tracer every ``span``
+   and ``enqueue_span`` also enters a ``jax.profiler.TraceAnnotation``
+   of its name (no arguments, so nothing is formatted), so program
+   spans sit on the host plane of a profile, on the profiler's clock
+   with the device ops. ``NULL_TRACER`` annotates nothing.
+
+5. **Parent links.** Every record carries an ``id`` and a ``parent``:
+   the innermost span open on the same thread when it began (0 at the
+   top), kept on a thread-local stack only while the tracer is
+   enabled. A layer's self time is its span minus its children.
 
 Span taxonomy (the names the exporter and the bench's stage-breakdown
-join rely on — see docs/architecture.md "Observability"):
+join rely on — see docs/architecture.md "Observability"). ``cat``
+``enqueue`` marks a span that ends when the host may proceed, not when
+the device work it issued completes:
 
-=====================  ====  =======================================
-name                   ph    emitted by
-=====================  ====  =======================================
-``request.submit``     i     ``Scheduler.submit`` (mints trace id)
-``route``              X     scheduler, around ``Router.route``
-``request.admit``      i     scheduler, per admitted dispatch group
-``hub.park``           i     scheduler, rows parked on ``NotResident``
-``hub.stage``          X     hub worker/inline, checkpoint → host
-``hub.commit``         X     hub, host → device slot install (enqueue)
-``kv.requeue``         i     scheduler, ``PagePoolExhausted`` rollback
-``wave.prefill``       X     engine, admit enqueue → harvest sync
-``wave.chunk``         i     engine, one chunked-prefill dispatch
-``wave.decode``        X     engine, decode tick(s) → harvest sync
-``wave.verify``        X     engine, speculative verify → harvest sync
-``spec.fallback``      i     engine, wave gated to plain decode
-``request.finish``     i     scheduler harvest (per response)
-=====================  ====  =======================================
+=====================  ====  =======  ==================================
+name                   ph    cat      emitted by
+=====================  ====  =======  ==================================
+``request.submit``     i     host     ``Scheduler.submit`` (trace id)
+``route``              X     host     scheduler, around ``Router.route``;
+                                      ``rows``, ``uids``, ``ahead``
+``route.wait``         X     host     router, each blocking device→host
+                                      transfer inside ``Router.route``
+``step``               X     enqueue  ``Scheduler.step``: the parent of
+                                      everything one round does
+``engine.enqueue``     X     enqueue  engine, each prefill / chunk /
+                                      decode / verify dispatch; ``kind``
+``engine.sync``        X     host     engine, the ``device_get`` of
+                                      ``_materialize``/``_materialize_spec``
+``request.admit``      i     host     scheduler, per admitted group
+``hub.park``           i     host     scheduler, rows parked on
+                                      ``NotResident``
+``hub.stage``          X     host     hub worker/inline, checkpoint → host
+``hub.commit``         X     enqueue  hub, host → device slot install
+``kv.requeue``         i     host     scheduler, ``PagePoolExhausted``
+                                      rollback
+``wave.prefill``       X     device   engine, admit enqueue → harvest sync
+``wave.chunk``         i     host     engine, one chunked-prefill dispatch
+``wave.decode``        X     device   engine, decode tick(s) → harvest sync
+``wave.verify``        X     device   engine, speculative verify → harvest
+``spec.fallback``      i     host     engine, wave gated to plain decode
+``request.finish``     i     host     scheduler harvest (per response)
+=====================  ====  =======  ==================================
+
+``ahead`` on ``route`` is the number of engine dispatches issued but
+not yet covered by a completed sync when routing began, summed over the
+engines (``EngineCore.ahead``): on one chip's in-order stream, an upper
+bound on the work a blocking transfer in the router waits behind.
 """
 from __future__ import annotations
 
@@ -49,6 +80,18 @@ import json
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+
+_UNRESOLVED = object()
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation``, or None where JAX is missing."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
 
 
 class _Span:
@@ -62,7 +105,8 @@ class _Span:
     ``error`` attribute) so span balance holds under rollback paths.
     """
 
-    __slots__ = ("_tracer", "name", "cat", "args", "t0", "ms")
+    __slots__ = ("_tracer", "name", "cat", "args", "t0", "ms", "id",
+                 "parent", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Dict[str, Any]):
@@ -72,12 +116,17 @@ class _Span:
         self.args = args
         self.t0 = 0.0
         self.ms = 0.0
+        self.id = 0             # 0: not opened on an enabled tracer
+        self.parent = 0
+        self._ann = None
 
     def set(self, **attrs: Any) -> "_Span":
         self.args.update(attrs)
         return self
 
     def __enter__(self) -> "_Span":
+        if self._tracer.enabled:
+            self._tracer._enter(self)
         self.t0 = time.perf_counter()
         return self
 
@@ -86,23 +135,28 @@ class _Span:
         self.ms = (t1 - self.t0) * 1e3
         if etype is not None:
             self.args.setdefault("error", etype.__name__)
-        if self._tracer.enabled:
-            self._tracer._append(self.name, self.cat, "X", self.t0,
-                                 t1 - self.t0, self.args)
+        tracer = self._tracer
+        if self.id:
+            tracer._exit(self)
+        if tracer.enabled:
+            tracer._append(self.name, self.cat, "X", self.t0,
+                           t1 - self.t0, self.args, span_id=self.id,
+                           parent=self.parent)
         return False
 
 
 class _DeviceSpan:
     """Open device-work handle: begun at enqueue, ended at a sync site."""
 
-    __slots__ = ("name", "args", "t0", "tid")
+    __slots__ = ("name", "args", "t0", "tid", "parent")
 
     def __init__(self, name: str, args: Dict[str, Any], t0: float,
-                 tid: str):
+                 tid: str, parent: int):
         self.name = name
         self.args = args
         self.t0 = t0
         self.tid = tid
+        self.parent = parent
 
 
 class Tracer:
@@ -122,6 +176,8 @@ class Tracer:
         self._seq = 0
         self._uid_trace: Dict[Any, int] = {}
         self._open: Dict[int, _DeviceSpan] = {}
+        self._local = threading.local()   # .stack: open spans, innermost last
+        self._annotation: Any = _UNRESOLVED
 
     # -- clock / ids ---------------------------------------------------
     def now(self) -> float:
@@ -155,6 +211,34 @@ class Tracer:
             self._uid_trace.pop(uid, None)
 
     # -- spans ---------------------------------------------------------
+    def _stack(self) -> List[_Span]:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    def _parent(self) -> int:
+        stack = getattr(self._local, "stack", None)
+        return stack[-1].id if stack else 0
+
+    def _enter(self, sp: _Span) -> None:
+        """Enabled tracers only: give ``sp`` an id and its parent, push
+        it on this thread's stack and enter its profiler annotation."""
+        sp.parent = self._parent()
+        sp.id = self.next_id()
+        self._stack().append(sp)
+        if self._annotation is _UNRESOLVED:
+            self._annotation = _profiler_annotation()
+        if self._annotation is not None:
+            sp._ann = self._annotation(sp.name)
+            sp._ann.__enter__()
+
+    def _exit(self, sp: _Span) -> None:
+        if sp._ann is not None:
+            sp._ann.__exit__(None, None, None)
+            sp._ann = None
+        self._stack().remove(sp)
+
     def span(self, name: str, /, **attrs: Any) -> _Span:
         """Host-work span. Must NOT wrap bare device dispatch — rule
         O002 flags that; use ``begin_device``/``end_device`` (completion
@@ -186,7 +270,7 @@ class Tracer:
         if not self.enabled:
             return None
         h = _DeviceSpan(name, attrs, time.perf_counter(),
-                        threading.current_thread().name)
+                        threading.current_thread().name, self._parent())
         with self._lock:
             self._open[id(h)] = h
         return h
@@ -203,7 +287,8 @@ class Tracer:
         with self._lock:
             self._open.pop(id(handle), None)
         self._append(handle.name, "device", "X", handle.t0,
-                     t1 - handle.t0, handle.args, tid=handle.tid)
+                     t1 - handle.t0, handle.args, tid=handle.tid,
+                     parent=handle.parent)
 
     def open_device_count(self) -> int:
         """Device spans begun but not yet ended — 0 after a full drain
@@ -215,13 +300,19 @@ class Tracer:
     # -- storage / export ----------------------------------------------
     def _append(self, name: str, cat: str, ph: str, t0: float,
                 dur_s: float, args: Dict[str, Any],
-                tid: Optional[str] = None) -> None:
+                tid: Optional[str] = None, span_id: int = 0,
+                parent: Optional[int] = None) -> None:
         rec = {"name": name, "cat": cat, "ph": ph,
                "ts": (t0 - self._epoch) * 1e6,
                "dur": dur_s * 1e6,
                "tid": tid or threading.current_thread().name,
+               "parent": self._parent() if parent is None else parent,
                "args": args}
         with self._lock:
+            if not span_id:
+                self._seq += 1
+                span_id = self._seq
+            rec["id"] = span_id
             self._records.append(rec)
 
     def records(self) -> List[Dict[str, Any]]:

@@ -301,6 +301,8 @@ class _Wave:
     wave_id: int = 0
     sp_prefill: Any = None
     sp_decode: Any = None
+    # sequence number of the wave's latest dispatch (EngineCore.ahead)
+    last_seq: int = 0
 
 
 class EngineCore:
@@ -347,6 +349,11 @@ class EngineCore:
         # the server carries a live tracer. Under NULL_TRACER every
         # call below is a no-op (begin_device returns None).
         self.tracer = NULL_TRACER
+        # dispatches issued, and the newest of them a completed sync
+        # covered: on an in-order device stream everything issued up to
+        # the dispatch that produced a materialised plane is done
+        self._issued = 0
+        self._covered = 0
         self._active: List[_Wave] = []
         self._finished: List[Tuple[int, Any, np.ndarray]] = []
         # shape-keyed jit wrappers; real executable counts come from
@@ -522,6 +529,13 @@ class EngineCore:
         them inside its existing sync sites, so binding a live tracer
         cannot change ``stats.host_blocks``."""
         self.tracer = tracer if tracer is not None else NULL_TRACER
+
+    @property
+    def ahead(self) -> int:
+        """Prefill, chunk, decode and verify dispatches issued after the
+        last one a completed ``device_get`` covered — what a blocking
+        transfer issued now would wait behind on an in-order stream."""
+        return self._issued - self._covered
 
     def executable_bounds(self) -> Dict[str, int]:
         """Steady-state executable-count bound per wrapper family.
@@ -809,6 +823,7 @@ class EngineCore:
             done[local] = [False] * len(u)
             n_rows += len(u)
         fb0 = self.stats.spec_fallback_waves
+        issued0 = self._issued
         if self.kv_layout == "paged":
             # may raise PagePoolExhausted with no state changed — the
             # scheduler requeues the rows as backpressure; the device
@@ -816,12 +831,16 @@ class EngineCore:
             # balance holds trivially across the rollback
             w = self._admit_paged(toks, uids, per_row, done, Bb, Sb)
         else:
-            logits, cache = self._prefill_fn(Bb, Sb)(
-                self.params, {"tokens": jnp.asarray(toks)})
+            with self.tracer.enqueue_span("engine.enqueue",
+                                          kind="prefill"):
+                logits, cache = self._prefill_fn(Bb, Sb)(
+                    self.params, {"tokens": jnp.asarray(toks)})
+                tok = jnp.argmax(logits, axis=-1).astype(
+                    jnp.int32)[..., None]
+            self._issued += 1
             self.stats.prefill_calls += 1
             self.stats.prefill_rows_computed += n_rows
             self.stats.prefill_tokens_computed += n_rows * Sb
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[..., None]
             steps = max(m for ms in per_row.values() for m in ms) - 1
             sk = self.speculate_k
             # no-wrap gate: every slot a verify may optimistically write
@@ -840,6 +859,8 @@ class EngineCore:
                 w = _Wave(uids=uids, per_row_new=per_row, done=done,
                           cache=cache, tok=tok, emitted=[tok[..., 0]],
                           steps_left=steps)
+        if self._issued > issued0:
+            w.last_seq = self._issued
         self.stats.rows_served += n_rows
         self.stats.rows_padded += E * Bb - n_rows
         self.stats.prefill_tokens_submitted += n_submitted
@@ -1126,11 +1147,14 @@ class EngineCore:
                         toks_c[local, c] = toks[local, i]
                         stbl[local, c] = scatter[(local, i)]
                         slot_of[(local, i)] = c
-                logits, self.kv_pool = self._prefill_fn(Bbc, Sb)(
-                    self.params, {"tokens": jnp.asarray(toks_c)},
-                    self.kv_pool, jnp.asarray(stbl))
+                with self.tracer.enqueue_span("engine.enqueue",
+                                              kind="prefill"):
+                    logits, self.kv_pool = self._prefill_fn(Bbc, Sb)(
+                        self.params, {"tokens": jnp.asarray(toks_c)},
+                        self.kv_pool, jnp.asarray(stbl))
+                    tok_c = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                self._issued += 1
                 self.stats.prefill_calls += 1
-                tok_c = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 src = np.zeros((E, Bb), np.int32)
                 for local, row_uids in uids.items():
                     for i in range(len(row_uids)):
@@ -1211,15 +1235,19 @@ class EngineCore:
         d = w.pending_chunks.pop(0)
         k = d["k"]
         Bbk = d["toks"].shape[1]
-        if k == 0:
-            logits, self.kv_pool = self._prefill_fn(Bbk, self.chunk_len)(
-                self.params, {"tokens": jnp.asarray(d["toks"])},
-                self.kv_pool, jnp.asarray(d["stbl"]))
-        else:
-            logits, self.kv_pool = self._suffix_fn(Bbk, k)(
-                self.params, {"tokens": jnp.asarray(d["toks"])},
-                self.kv_pool, jnp.asarray(d["ptbl"]),
-                jnp.asarray(d["stbl"]))
+        with self.tracer.enqueue_span("engine.enqueue", kind="chunk"):
+            if k == 0:
+                logits, self.kv_pool = self._prefill_fn(
+                    Bbk, self.chunk_len)(
+                    self.params, {"tokens": jnp.asarray(d["toks"])},
+                    self.kv_pool, jnp.asarray(d["stbl"]))
+            else:
+                logits, self.kv_pool = self._suffix_fn(Bbk, k)(
+                    self.params, {"tokens": jnp.asarray(d["toks"])},
+                    self.kv_pool, jnp.asarray(d["ptbl"]),
+                    jnp.asarray(d["stbl"]))
+        self._issued += 1
+        w.last_seq = self._issued
         self.stats.prefill_calls += 1
         spent = d["rows"] * self.chunk_len
         self.stats.prefill_tokens_computed += spent
@@ -1309,17 +1337,23 @@ class EngineCore:
                     if not defer:
                         self._materialize_spec(w)
                     continue
-                if self.kv_layout == "paged":
-                    # the pool buffers thread through every wave's tick
-                    # (donated each dispatch); pos/t stay per-wave
-                    logits, self.kv_pool, w.pos, w.t = self._decode_fn(
-                        Bb)(self.params, self.kv_pool, w.table, w.pos,
-                            w.t, {"token": w.tok})
-                else:
-                    logits, w.cache = self._decode_fn(Bb)(
-                        self.params, w.cache, {"token": w.tok})
-                w.tok = jnp.argmax(logits, axis=-1).astype(
-                    jnp.int32)[..., None]
+                with self.tracer.enqueue_span("engine.enqueue",
+                                              kind="decode"):
+                    if self.kv_layout == "paged":
+                        # the pool buffers thread through every wave's
+                        # tick (donated each dispatch); pos/t stay
+                        # per-wave
+                        logits, self.kv_pool, w.pos, w.t = \
+                            self._decode_fn(Bb)(
+                                self.params, self.kv_pool, w.table,
+                                w.pos, w.t, {"token": w.tok})
+                    else:
+                        logits, w.cache = self._decode_fn(Bb)(
+                            self.params, w.cache, {"token": w.tok})
+                    w.tok = jnp.argmax(logits, axis=-1).astype(
+                        jnp.int32)[..., None]
+                self._issued += 1
+                w.last_seq = self._issued
                 w.emitted.append(w.tok[..., 0])
                 w.steps_left -= 1
                 self.stats.decode_steps += 1
@@ -1339,14 +1373,18 @@ class EngineCore:
         has its tokens."""
         args = (w.row_pos, w.row_t, w.tok[..., 0], w.cap,
                 self.draft_state)
-        if self.kv_layout == "paged":
-            (emit, adv, acc, tok2, self.kv_pool, w.row_pos, w.row_t,
-             self.draft_state) = self._verify_fn(Bb, self.speculate_k)(
-                self.params, self.kv_pool, w.table, *args)
-        else:
-            (emit, adv, acc, tok2, w.cache, w.row_pos, w.row_t,
-             self.draft_state) = self._verify_fn(Bb, self.speculate_k)(
-                self.params, w.cache, *args)
+        with self.tracer.enqueue_span("engine.enqueue", kind="verify"):
+            if self.kv_layout == "paged":
+                (emit, adv, acc, tok2, self.kv_pool, w.row_pos, w.row_t,
+                 self.draft_state) = self._verify_fn(
+                    Bb, self.speculate_k)(
+                    self.params, self.kv_pool, w.table, *args)
+            else:
+                (emit, adv, acc, tok2, w.cache, w.row_pos, w.row_t,
+                 self.draft_state) = self._verify_fn(
+                    Bb, self.speculate_k)(self.params, w.cache, *args)
+        self._issued += 1
+        w.last_seq = self._issued
         w.tok = tok2[..., None]
         w.spec_pending.append((emit, adv, acc))
         w.steps_left -= 1
@@ -1359,7 +1397,11 @@ class EngineCore:
         upto = min(upto, len(w.emitted))
         if upto <= w.n_host:
             return
-        host = jax.device_get(w.emitted[w.n_host:upto])
+        with self.tracer.span("engine.sync"):
+            host = jax.device_get(w.emitted[w.n_host:upto])
+        if upto == len(w.emitted):
+            # the newest plane came out of the wave's latest dispatch
+            self._covered = max(self._covered, w.last_seq)
         for k, plane in enumerate(host):
             w.emitted[w.n_host + k] = np.asarray(plane)
         w.n_host = upto
@@ -1383,7 +1425,10 @@ class EngineCore:
         instead of steps."""
         if w.spec_seeded and not w.spec_pending:
             return
-        first, triples = jax.device_get((w.emitted[0], w.spec_pending))
+        with self.tracer.span("engine.sync"):
+            first, triples = jax.device_get((w.emitted[0],
+                                             w.spec_pending))
+        self._covered = max(self._covered, w.last_seq)
         self.stats.host_blocks += 1
         # blessed sync site (the speculative twin of _materialize)
         if w.sp_prefill is not None:

@@ -34,6 +34,7 @@ import numpy as np
 
 from ..core import autoencoder as ae
 from ..core.matcher import ExpertMatcher
+from ..obs.trace import NULL_TRACER
 from .engine import bucket_for, make_buckets
 
 
@@ -110,6 +111,9 @@ class Router:
         # and route() increments under it (rule R001)
         self.expert_hits: collections.Counter = collections.Counter()
         self.hits_lock: Optional[threading.Lock] = None
+        # the scheduler's tracer (Scheduler.bind_tracer): each blocking
+        # device-to-host transfer below is a route.wait span
+        self.tracer = NULL_TRACER
         self._coarse = jax.jit(matcher.assign_coarse_topk)
         self._fine_ref = jax.jit(matcher.assign_fine)
         # encode a group under ONE expert's AE (params sliced by index)
@@ -144,7 +148,9 @@ class Router:
             z = self._encode_at(xg, jnp.int32(e))
             sim = kops.cosine_scores(z, m.centroids[int(e)],
                                      m.centroid_mask[int(e)])
-            fine[rows] = np.asarray(jnp.argmax(sim, axis=-1))[:n]
+            best = jnp.argmax(sim, axis=-1)
+            with self.tracer.span("route.wait"):
+                fine[rows] = np.asarray(best)[:n]
             self.stats["score_calls"] += 1
         return fine
 
@@ -178,13 +184,16 @@ class Router:
             xm = feats[chunk]
             xp, n = self._pad_rows(xm)
             c, s = self._coarse(xp)
-            c = np.asarray(c)[:n]
-            s = np.asarray(s)[:n]
+            with self.tracer.span("route.wait"):
+                c = np.asarray(c)[:n]
+                s = np.asarray(s)[:n]
             if self.use_fine_kernel:
                 f = self._fine_grouped(xm, c[:, 0])
             elif self.matcher.centroids is not None:
-                f = np.asarray(self._fine_ref(xp, jnp.asarray(
-                    np.pad(c[:, 0], (0, len(xp) - n)))))[:n]
+                f = self._fine_ref(xp, jnp.asarray(
+                    np.pad(c[:, 0], (0, len(xp) - n))))
+                with self.tracer.span("route.wait"):
+                    f = np.asarray(f)[:n]
             else:
                 f = np.zeros(n, np.int64)
             for j, i in enumerate(chunk):

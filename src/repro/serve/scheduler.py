@@ -275,13 +275,24 @@ class Scheduler:
         and on the hub (None restores the disabled NULL_TRACER). Safe
         between steps; rows already in flight keep trace id 0."""
         self.tracer = tracer if tracer is not None else NULL_TRACER
-        for shard in self.shards:
-            eng = self._shard_engine(shard)
-            core = getattr(eng, "core", None)
-            if core is not None:
-                core.bind_tracer(self.tracer)
+        for core in self._cores():
+            core.bind_tracer(self.tracer)
         if self.hub is not None:
             self.hub.bind_tracer(self.tracer)
+        if self.router is not None:
+            self.router.tracer = self.tracer
+
+    def _cores(self) -> List[Any]:
+        """The engine core behind each shard that has one."""
+        cores = (getattr(self._shard_engine(s), "core", None)
+                 for s in self.shards)
+        return [c for c in cores if c is not None]
+
+    def _dispatches_ahead(self) -> int:
+        """Engine dispatches not yet covered by a completed sync, summed
+        over the engines: on one chip's in-order stream, an upper bound
+        on what a blocking transfer issued now waits behind."""
+        return sum(core.ahead for core in self._cores())
 
     def _build_metrics(self) -> MetricsRegistry:
         """The unified snapshot tree: scheduler counters + latency
@@ -376,8 +387,12 @@ class Scheduler:
                 "pre-routed (Request.expert set)")
         routed = None
         if miss:
-            with self.tracer.span("route", rows=len(miss),
-                                  uids=[requests[i].uid for i in miss]):
+            args = {}
+            if self.tracer.enabled:
+                args = {"rows": len(miss),
+                        "uids": [requests[i].uid for i in miss],
+                        "ahead": self._dispatches_ahead()}
+            with self.tracer.span("route", **args):
                 routed = self.router.route(np.stack(
                     [requests[i].features for i in miss]))
         routed_at = {i: j for j, i in enumerate(miss)}
@@ -434,8 +449,12 @@ class Scheduler:
 
     # -- one scheduling round -------------------------------------------
     def step(self) -> List[Response]:
-        self.executor.run_step(self)
-        self._harvest()
+        # enqueue_span: the round ends when the host may go on, not when
+        # the ticks it enqueued complete; its engine.enqueue and
+        # engine.sync children split it into dispatch and device wait
+        with self.tracer.enqueue_span("step"):
+            self.executor.run_step(self)
+            self._harvest()
         out, self._done = self._done, []
         self._counters["responses"].inc(len(out))
         self._steps += 1

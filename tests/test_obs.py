@@ -14,9 +14,15 @@ The tentpole claims, each proven here against real serving traffic:
   * **snapshot stability** — ``obs.snapshot()`` exposes one stable tree
     (scheduler / engines / kv / hub / executor) whose keys downstream
     dashboards may rely on;
+  * **program spans** — a traced ``RoutedServer`` records ``step`` with
+    its ``engine.enqueue``/``engine.sync`` children and ``route`` (with
+    ``ahead``) with its ``route.wait`` children, linked by ``parent``,
+    and puts every span on the profiler's host plane;
   * **the static gate** — planted O001/O002/O003 violations are caught,
     and the compliant idioms pass (mirrors tests/test_analysis.py).
 """
+import glob
+import sys
 import textwrap
 
 import jax
@@ -26,6 +32,8 @@ import pytest
 from repro.analysis import obs_lint
 from repro.core import ExpertRegistry
 from repro.configs import get_config
+from repro.core import build_matcher, train_bank
+from repro.data import load_benchmark
 from repro.models import build_model
 from repro.obs import (Counter, DEFAULT_MS_BUCKETS, Gauge, Histogram,
                        MetricsRegistry, NULL_TRACER, Tracer)
@@ -42,6 +50,36 @@ def model():
 @pytest.fixture(scope="module")
 def params2(model):
     return [model.init(jax.random.PRNGKey(s)) for s in range(2)]
+
+
+@pytest.fixture(scope="module")
+def routed(model, params2):
+    """A matcher over two synthetic datasets (routing accuracy does not
+    matter here, so it trains one epoch) and client fingerprints."""
+    data = load_benchmark(names=["mnist", "har"], n_per_dataset=100,
+                          seed=0)
+    names = list(data)
+    aes, _ = train_bank([(n, data[n]["server"][0]) for n in names],
+                        epochs=1, batch_size=64)
+    m = build_matcher(aes, names, [data[n]["server"] for n in names])
+    feats = np.concatenate([data[n]["client_a"][0][:8] for n in names])
+    return m, names, feats
+
+
+def _routed_server(model, params2, routed, tracer):
+    m, names, _ = routed
+    reg = ExpertRegistry()
+    for n, p in zip(names, params2):
+        reg.add(n, ExpertEngine(model, p, max_len=32))
+    return RoutedServer(m, reg, max_batch=4, tracer=tracer)
+
+
+def _routed_reqs(feats, lo, n, max_new=4):
+    rng = np.random.default_rng(lo)
+    return [Request(uid=lo + i, features=feats[(lo + i) % len(feats)],
+                    prompt=rng.integers(0, 50, size=int(rng.integers(3, 20))),
+                    max_new_tokens=max_new)
+            for i in range(n)]
 
 
 def _reqs(rng, n, n_experts, lo=3, hi=28, max_new=(1, 5)):
@@ -271,6 +309,154 @@ def test_host_blocks_identical_with_tracing_on(model, params2):
     # and the trace really recorded the work it didn't perturb
     assert tracer.open_device_count() == 0
     assert len(_by(tracer.records(), "request.finish")) == 10
+
+
+# -- program spans: the step tree, routing waits, the profiler bridge -------
+
+
+def test_program_spans_form_the_step_and_route_trees(model, params2,
+                                                     routed):
+    """Every new span appears on a traced RoutedServer, each linked to
+    the innermost span open on its thread: ``engine.enqueue`` and
+    ``engine.sync`` under ``step``, ``route.wait`` under ``route``.
+    ``route`` carries ``ahead``: dispatches issued and not yet covered
+    by a completed sync, none once the server has drained."""
+    tracer = Tracer()
+    srv = _routed_server(model, params2, routed, tracer)
+    feats = routed[2]
+    srv.submit(_routed_reqs(feats, 0, 4))
+    srv.step()                     # prefill + first tick, still in flight
+    srv.submit(_routed_reqs(feats, 100, 4))
+    srv.scheduler.drain()
+    assert srv.scheduler._dispatches_ahead() == 0
+    srv.submit(_routed_reqs(feats, 200, 2))
+    assert len(srv.scheduler.drain()) == 2
+
+    recs = tracer.records()
+    by_id = {r["id"]: r for r in recs}
+    assert len(by_id) == len(recs)                      # ids are unique
+    for name in ("step", "engine.enqueue", "engine.sync", "route",
+                 "route.wait"):
+        assert _by(recs, name), f"no {name} span"
+    for r in _by(recs, "step") + _by(recs, "route"):
+        assert r["parent"] == 0
+    for name, parent in (("engine.enqueue", "step"),
+                         ("engine.sync", "step"),
+                         ("route.wait", "route")):
+        for r in _by(recs, name):
+            p = by_id[r["parent"]]
+            assert p["name"] == parent
+            assert p["ts"] <= r["ts"] and \
+                r["ts"] + r["dur"] <= p["ts"] + p["dur"]
+    assert all(r["cat"] == "enqueue" for r in _by(recs, "engine.enqueue"))
+    assert {r["args"]["kind"] for r in _by(recs, "engine.enqueue")} == \
+        {"prefill", "decode"}
+    # device spans and events hang off the span open where they began
+    for r in recs:
+        if r["cat"] == "device" or r["ph"] == "i":
+            assert r["parent"] == 0 or r["parent"] in by_id
+    assert any(by_id[r["parent"]]["name"] == "step"
+               for r in _by(recs, "wave.prefill"))
+
+    routes = _by(recs, "route")
+    assert [r["args"]["rows"] for r in routes] == [4, 4, 2]
+    assert [r["args"]["uids"] for r in routes][2] == [200, 201]
+    ahead = [r["args"]["ahead"] for r in routes]
+    assert ahead[0] == 0 and ahead[1] >= 2 and ahead[2] == 0
+    assert tracer.open_device_count() == 0
+
+
+def test_host_blocks_identical_with_tracing_on_routed(model, params2,
+                                                     routed):
+    """The new engine.sync spans wrap the engine's existing device_get:
+    routed traffic blocks the host exactly as often, and serves the same
+    tokens, with a live tracer as without one."""
+    feats = routed[2]
+
+    def serve(tracer):
+        srv = _routed_server(model, params2, routed, tracer)
+        srv.submit(_routed_reqs(feats, 0, 6))
+        srv.step()
+        srv.submit(_routed_reqs(feats, 50, 6, max_new=3))
+        out = {r.uid: r.tokens for r in srv.scheduler.drain()}
+        reg = srv.scheduler.registry
+        return out, [reg[e].backend.stats.host_blocks for e in range(2)]
+
+    got_off, blocks_off = serve(None)
+    tracer = Tracer()
+    got_on, blocks_on = serve(tracer)
+    assert blocks_on == blocks_off and sum(blocks_off) > 0
+    assert sorted(got_on) == sorted(got_off) and len(got_off) == 12
+    for uid in got_off:
+        np.testing.assert_array_equal(got_on[uid], got_off[uid],
+                                      err_msg=str(uid))
+    # one engine.sync per host block
+    assert len(_by(tracer.records(), "engine.sync")) == sum(blocks_on)
+
+
+def test_program_spans_reach_the_profiler_host_plane(tmp_path, model,
+                                                     params2, routed):
+    """Under ``jax.profiler.trace`` each program span of an enabled
+    tracer is a host-plane event of the profile, read back with
+    ``jax.profiler.ProfileData``; a span on ``NULL_TRACER`` is neither
+    recorded nor annotated."""
+    tracer = Tracer()
+    srv = _routed_server(model, params2, routed, tracer)
+    feats = routed[2]
+    srv.serve(_routed_reqs(feats, 0, 2))       # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        with NULL_TRACER.span("obs.null_probe") as probe:
+            pass
+        srv.submit(_routed_reqs(feats, 10, 4))
+        srv.scheduler.drain()
+    assert probe.id == 0 and NULL_TRACER.records() == []
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                     recursive=True)[0]
+    pd = jax.profiler.ProfileData.from_file(path)
+    names = {ev.name for p in pd.planes if p.name.startswith("/host:")
+             for line in p.lines for ev in line.events}
+    assert {"step", "engine.enqueue", "engine.sync", "route",
+            "route.wait"} <= names
+    assert "obs.null_probe" not in names
+    spans = [r for r in tracer.records() if r["ph"] == "X"
+             and r["cat"] != "device"]
+    assert {r["name"] for r in spans} <= names
+
+
+def test_null_tracer_opens_no_span():
+    """The disabled path keeps no stack, mints no id and never resolves
+    the profiler bridge."""
+    with NULL_TRACER.span("route") as outer:
+        with NULL_TRACER.enqueue_span("engine.enqueue") as inner:
+            pass
+    assert outer.id == inner.id == 0 and inner.parent == 0
+    assert outer.ms >= inner.ms >= 0.0
+    assert not hasattr(NULL_TRACER._local, "stack")
+    assert NULL_TRACER.records() == []
+    # an enabled tracer nests, then unwinds its stack, on error too
+    t = Tracer()
+    with pytest.raises(RuntimeError):
+        with t.span("route"):
+            with t.span("route.wait"):
+                raise RuntimeError("boom")
+    wait, route = t.records()
+    assert wait["parent"] == route["id"] and route["parent"] == 0
+    assert wait["args"]["error"] == "RuntimeError"
+    assert t._stack() == []
+
+
+def test_tracer_without_jax_keeps_no_bridge(monkeypatch):
+    """``repro.obs`` promises to work without JAX: where
+    ``jax.profiler`` cannot be imported, an enabled tracer still records
+    and links its spans, and annotates nothing."""
+    monkeypatch.setitem(sys.modules, "jax.profiler", None)
+    t = Tracer()
+    with t.span("step"):
+        with t.enqueue_span("engine.enqueue", kind="decode") as sp:
+            pass
+    assert t._annotation is None and sp._ann is None
+    inner, outer = t.records()
+    assert inner["parent"] == outer["id"] != 0
 
 
 # -- snapshot tree stability -------------------------------------------------
