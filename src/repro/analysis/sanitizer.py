@@ -497,8 +497,8 @@ class _StubModel:
         return {"w": self._sds((4,), self._jnp.float32)}
 
     def init_paged_pool(self, n_pages: int, page: int):
-        # +1: physical page n_pages is the trash page
-        return {"k": self._jnp.zeros((n_pages + 1, page, 2),
+        # one layer of page rows; +1: physical page n_pages is the trash
+        return {"k": self._jnp.zeros((1, n_pages + 1, page * 2),
                                      self._jnp.float32)}
 
 

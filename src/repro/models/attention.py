@@ -173,69 +173,88 @@ def cache_prefill(cache, k, v):
     return {"k": ck, "v": cv, "pos": pos, "t": jnp.asarray(S, jnp.int32)}
 
 
-def cache_append(cache, k1, v1):
-    """Append one token (k1, v1: (B, 1, KV, dh)); ring-wraps automatically."""
-    C = cache["k"].shape[1]
-    t = cache["t"]
-    slot = t % C
-    ck = jax.lax.dynamic_update_slice(
-        cache["k"], k1.astype(cache["k"].dtype), (0, slot, 0, 0))
-    cv = jax.lax.dynamic_update_slice(
-        cache["v"], v1.astype(cache["v"].dtype), (0, slot, 0, 0))
-    pos = jax.lax.dynamic_update_slice(cache["pos"], t[None], (slot,))
-    return {"k": ck, "v": cv, "pos": pos, "t": t + 1}
-
-
 # ---------------------------------------------------------------------------
-# Paged KV cache protocol (single-layer primitives)
+# Paged KV cache protocol (device primitives)
 #
 # Instead of one dense (B, C, KV, dh) buffer per micro-batch, K/V live
-# in a shared pool of fixed-size pages (n_pages + 1, page, KV, dh) —
-# the trailing page is the *trash page*, a write-discard target for
-# rows whose computed KV is deliberately dropped (batch padding, rows
-# deduplicated against a shared prefix). Each row carries a page table
-# (B, C // page) of physical page ids; prefix-sharing rows simply map
-# leading logical pages to the same physical pages. `pos`/`t` tracking
-# is unchanged from the ring cache: positions are logical-slot-indexed
-# and rows advance in lockstep, so the attention masking math cannot
-# tell the layouts apart. Allocation/refcounting is host-side
-# (`repro.serve.kvcache.PagePool`); these helpers are the device half.
+# in a shared pool of fixed-size pages. A model stores each layer's
+# pages as *page rows*: (P1, page * KV * dh), one physical page per row,
+# where physical page n_pages is the *trash page*, a write-discard
+# target for rows whose computed KV is deliberately dropped (batch
+# padding, rows deduplicated against a shared prefix). Each row carries
+# a page table (B, C // page) of physical page ids; prefix-sharing rows
+# simply map leading logical pages to the same physical pages.
+# `pos`/`t` tracking is unchanged from the ring cache: positions are
+# logical-slot-indexed and rows advance in lockstep, so the attention
+# masking math cannot tell the layouts apart. Allocation/refcounting is
+# host-side (`repro.serve.kvcache.PagePool`); these helpers are the
+# device half.
 # ---------------------------------------------------------------------------
 
 
-def init_paged_pool(n_pages, page, n_kv, dh, dtype):
-    """Zeroed (n_pages + 1, page, KV, dh) pool; last page is trash."""
-    shape = (n_pages + 1, page, n_kv, dh)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-
-
-def paged_gather(k_pages, v_pages, table):
+def paged_gather(k_pages, v_pages, table, heads=None, layer=None):
     """Materialise each row's logical KV view through its page table.
 
-    k_pages, v_pages: (P1, page, KV, dh); table: (B, n) int32 physical
-    page per logical page. Returns dense (B, n * page, KV, dh) views
-    whose values equal the ring cache's for every written slot (unwritten
-    slots carry pool garbage — always masked via pos == -1).
+    k_pages, v_pages: (P1, page, KV, dh) pages, or (P1, page * KV * dh)
+    page rows with ``heads`` = (KV, dh); with ``layer`` given, a layer
+    stack (L, P1, page * KV * dh) read at ``layer`` (a scalar, or an
+    index array broadcast against ``table``) in the same gather — a
+    plane sliced out first, or a layer axis batched over, would copy or
+    relay out the pool. table: (B, n) int32 physical page per logical
+    page. Returns dense (..., B, n * page, KV, dh) views whose values
+    equal the ring cache's for every written slot (unwritten slots
+    carry pool garbage — always masked via pos == -1).
     """
-    B, n = table.shape
-    page, KV, dh = k_pages.shape[1:]
-    k = k_pages[table].reshape(B, n * page, KV, dh)
-    v = v_pages[table].reshape(B, n * page, KV, dh)
-    return k, v
+    at, lead = table, table.shape
+    if layer is not None:
+        at, lead = (layer, table), jnp.broadcast_shapes(jnp.shape(layer),
+                                                        lead)
+    shape = lead[:-1] + (-1,) + tuple(heads or k_pages.shape[-2:])
+    return k_pages[at].reshape(shape), v_pages[at].reshape(shape)
 
 
 def paged_scatter_pages(k_pages, v_pages, scatter_tbl, k, v):
-    """Write whole prefill pages: k, v (B, S, KV, dh) with S a multiple
-    of the page size; scatter_tbl (B, S // page) physical destinations.
-    Rows whose compute is discarded point every entry at the trash page
-    (duplicate trash indices are fine — the page is never read)."""
-    B, S, KV, dh = k.shape
-    npp = scatter_tbl.shape[1]
-    page = S // npp
-    ku = k.reshape(B, npp, page, KV, dh).astype(k_pages.dtype)
-    vu = v.reshape(B, npp, page, KV, dh).astype(v_pages.dtype)
-    return (k_pages.at[scatter_tbl].set(ku),
-            v_pages.at[scatter_tbl].set(vu))
+    """Write whole prefill pages into a layer stack of page rows
+    (L, P1, page * KV * dh), in place: k, v (L, B, S, KV, dh) with S a
+    multiple of the page size; scatter_tbl (B, S // page) physical
+    destinations. Rows whose compute is discarded point every entry at
+    the trash page (duplicate trash indices are fine — the page is
+    never read). One scatter per layer, in a loop: a single scatter of
+    every layer's rows compiles several times slower on the TPU."""
+    shape = scatter_tbl.shape + k_pages.shape[2:]
+
+    def put(l, pages, x):
+        return pages.at[l, scatter_tbl].set(
+            x[l].reshape(shape).astype(pages.dtype))
+
+    return jax.lax.fori_loop(
+        0, k_pages.shape[0],
+        lambda l, kv: (put(l, kv[0], k), put(l, kv[1], v)),
+        (k_pages, v_pages))
+
+
+def paged_write_slots(k_pages, v_pages, idx, k, v):
+    """Write single tokens' KV into page rows, in place.
+
+    k_pages, v_pages: (..., P1, page * KV * dh) page rows, optionally
+    under leading axes (a layer stack); idx: (N, k_pages.ndim) int32 per
+    written token: its leading indices, physical page and slot within
+    the page; k, v: (N, KV, dh). Each token is one (KV * dh)-wide window
+    of its page's row. Rows may only collide on the trash page, where
+    the winning write is irrelevant — the page is never read unmasked."""
+    N = idx.shape[0]
+    W = k.shape[-2] * k.shape[-1]
+    idx = idx.at[:, -1].multiply(W)
+    nd = k_pages.ndim
+    dn = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(1,), inserted_window_dims=tuple(range(nd - 1)),
+        scatter_dims_to_operand_dims=tuple(range(nd)))
+
+    def put(pages, x):
+        return jax.lax.scatter(pages, idx,
+                               x.reshape(N, W).astype(pages.dtype), dn)
+
+    return put(k_pages, k), put(v_pages, v)
 
 
 def suffix_attend(q, k_suf, v_suf, pk, pv, *, offset, window=0, chunk=0):
@@ -262,24 +281,3 @@ def suffix_attend(q, k_suf, v_suf, pk, pv, *, offset, window=0, chunk=0):
     kv_pos = jnp.concatenate([jnp.arange(offset), positions])
     return attention(q, fk, fv, q_pos=positions, kv_pos=kv_pos,
                      window=window, chunk=chunk)
-
-
-def paged_append(k_pages, v_pages, tbl_col, offset, k1, v1):
-    """Write one decoded token per row: tbl_col (B,) physical pages,
-    offset () in-page slot (shared — rows decode in lockstep), k1, v1
-    (B, 1, KV, dh)."""
-    return (k_pages.at[tbl_col, offset].set(k1[:, 0].astype(k_pages.dtype)),
-            v_pages.at[tbl_col, offset].set(v1[:, 0].astype(v_pages.dtype)))
-
-
-def paged_append_rows(k_pages, v_pages, tbl_cols, offsets, kw, vw):
-    """Write W tokens per row at *per-row* slots — the speculative
-    verify scatter, where each row's write window starts at its own
-    ``t``. tbl_cols, offsets: (B, W) physical page / in-page slot per
-    written token; kw, vw: (B, W, KV, dh). Advanced indexing pairs the
-    two index arrays elementwise, so (b, w) lands in
-    ``pages[tbl_cols[b, w], offsets[b, w]]``. Rows may only collide on
-    the trash page (write windows are wave-owned per row), where the
-    winning write is irrelevant — the page is never read unmasked."""
-    return (k_pages.at[tbl_cols, offsets].set(kw.astype(k_pages.dtype)),
-            v_pages.at[tbl_cols, offsets].set(vw.astype(v_pages.dtype)))

@@ -12,9 +12,9 @@ import jax
 import jax.numpy as jnp
 
 from .api import BaseModel, register_family
-from .attention import (attention, cache_append, cache_prefill,
-                        init_kv_cache, paged_append, paged_append_rows,
-                        paged_gather, paged_scatter_pages, suffix_attend)
+from .attention import (attention, cache_prefill, init_kv_cache,
+                        paged_gather, paged_scatter_pages,
+                        paged_write_slots, suffix_attend)
 from .common import (ArchConfig, KeyGen, apply_rope, dense_init, dt,
                      embed_init, ones_init, rmsnorm, softmax_xent, zeros_init)
 from .moe import init_moe, moe_ffn
@@ -356,41 +356,46 @@ class DecoderLM(BaseModel):
     def paged_verify(self, params, pool, table, pos, t, batch, *, page):
         """Paged-layout verify: gather each row's dense view through its
         page table, run the ring ``verify`` on it, scatter the K+1
-        optimistically written slots back through ``paged_append_rows``
-        at per-row offsets. Same identity-by-construction argument as
-        ``paged_decode``. pos: (B, C), t: (B,); returns (greedy, pool')."""
+        optimistically written slots back at per-row offsets. Same
+        identity-by-construction argument as ``paged_decode``. pos:
+        (B, C), t: (B,); returns (greedy, pool')."""
+        cfg = self.cfg
+        heads = (cfg.n_kv_heads, cfg.dh)
+        layers = jnp.arange(cfg.n_layers)
         tokens = batch["tokens"]
-        K1 = tokens.shape[1]
-        nlp = table.shape[1]
-        C = nlp * page
-        gk, gv = jax.vmap(paged_gather, in_axes=(1, 1, None),
-                          out_axes=0)(pool["k"], pool["v"], table)
+        B, K1 = tokens.shape
+        C = table.shape[1] * page
+        gk, gv = paged_gather(pool["k"], pool["v"], table, heads,
+                              layer=layers[:, None, None])
         greedy, nc = self.verify(params, {"k": gk, "v": gv}, pos, t,
                                  batch)
         slots = (t[:, None] + jnp.arange(K1)[None, :]) % C     # (B, K1)
         tbl_cols = jnp.take_along_axis(table, slots // page, axis=1)
-        offs = slots % page
-        idx = slots[:, :, None, None]
-
-        def per_layer(kp, vp, kl, vl):
-            kw = jnp.take_along_axis(kl, idx, axis=1)          # (B, K1, ...)
-            vw = jnp.take_along_axis(vl, idx, axis=1)
-            return paged_append_rows(kp, vp, tbl_cols, offs, kw, vw)
-
-        nk, nv = jax.vmap(per_layer, in_axes=(1, 1, 0, 0),
-                          out_axes=(1, 1))(pool["k"], pool["v"],
-                                           nc["k"], nc["v"])
+        idx = jnp.broadcast_arrays(layers[:, None, None], tbl_cols[None],
+                                   (slots % page)[None])
+        idx = jnp.stack(idx, -1).reshape(-1, 3)    # (L * B * K1, 3)
+        sel = slots[None, :, :, None, None]
+        kw = jnp.take_along_axis(nc["k"], sel, axis=2)     # (L, B, K1, ...)
+        vw = jnp.take_along_axis(nc["v"], sel, axis=2)
+        nk, nv = paged_write_slots(pool["k"], pool["v"], idx,
+                                   kw.reshape(-1, *heads),
+                                   vw.reshape(-1, *heads))
         return greedy, {"k": nk, "v": nv}
 
     # ------------------------------------------------------------------
-    # Paged KV cache protocol. The forward math is *shared with the ring
-    # path by construction*: paged_prefill runs the ordinary prefill and
-    # only then scatters the dense cache into pool pages; paged_decode
-    # gathers each row's pages into the dense view the ordinary decode
-    # expects and scatters back the one slot it wrote. Logits therefore
-    # go through the identical op sequence in both layouts — the
-    # token-identity the serving equivalence tests assert is a property
-    # of the construction, not a numerical accident.
+    # Paged KV cache protocol. The pool is layer-major page rows: each
+    # of K and V is (L, P1, page * KV * dh), one physical page of one
+    # layer per row (see attention.py). The forward math is *shared with
+    # the ring path by construction*: paged_prefill runs the ordinary
+    # prefill and only then scatters the dense cache into pool pages;
+    # paged_decode's layer scan carries the pool, gathers each row's
+    # pages of layer l into the dense (B, C, KV, dh) view the ring
+    # decode's layer body expects, runs that body unchanged and writes
+    # the page row holding the one new slot back into the carried pool,
+    # in place. Logits therefore go through the same per-layer math on
+    # the same values in both layouts — the token-identity the serving
+    # equivalence tests assert is a property of the construction, not a
+    # numerical accident.
     # ------------------------------------------------------------------
     @property
     def supports_paged_kv(self):
@@ -399,10 +404,17 @@ class DecoderLM(BaseModel):
         return not self.cfg.n_stub_embeds
 
     def init_paged_pool(self, n_pages, page):
-        # layer-stack on axis 1: (P1, L, page, KV, dh) keeps the page
-        # index leading so one gather per table entry covers all layers
+        # Pages as rows, layer-major: a layer's pages are one (P1, R)
+        # plane that the decode scan gathers and writes in place, and
+        # a page is one row of R = page * KV * dh elements. The row
+        # count (n_pages + trash) is rounded up to a whole 8-row tile,
+        # which the tiled TPU layout pads to anyway: then the device's
+        # default layout keeps rows on the second-minor axis instead of
+        # moving a short, pad-free axis (layers, experts) there, which
+        # would put a transpose of the whole pool around every tick.
         cfg = self.cfg
-        shape = (n_pages + 1, cfg.n_layers, page, cfg.n_kv_heads, cfg.dh)
+        rows = -(-(n_pages + 1) // 8) * 8
+        shape = (cfg.n_layers, rows, page * cfg.n_kv_heads * cfg.dh)
         cdt = dt(cfg.compute_dtype)
         return {"k": jnp.zeros(shape, cdt), "v": jnp.zeros(shape, cdt)}
 
@@ -414,12 +426,8 @@ class DecoderLM(BaseModel):
         logits, cache = self.prefill(params, batch, capacity=capacity)
         S = batch["tokens"].shape[1]
         k, v = cache["k"][:, :, :S], cache["v"][:, :, :S]
-
-        def per_layer(kp, vp, kl, vl):
-            return paged_scatter_pages(kp, vp, scatter_tbl, kl, vl)
-
-        nk, nv = jax.vmap(per_layer, in_axes=(1, 1, 0, 0),
-                          out_axes=(1, 1))(pool["k"], pool["v"], k, v)
+        nk, nv = paged_scatter_pages(pool["k"], pool["v"], scatter_tbl,
+                                     k, v)
         return logits, {"k": nk, "v": nv}, cache["pos"], cache["t"]
 
     def paged_prefill_suffix(self, params, batch, pool, prefix_tbl,
@@ -432,12 +440,13 @@ class DecoderLM(BaseModel):
         last suffix position's — causal masking makes them identical to a
         monolithic prefill of the full offset+Ssuf prompt."""
         cfg = self.cfg
+        heads = (cfg.n_kv_heads, cfg.dh)
         x = self._embed(params, batch)
         Ssuf = x.shape[1]
         positions = jnp.arange(offset, offset + Ssuf)
         # gather the prefix view once per layer: (L, B, offset, KV, dh)
-        gk, gv = jax.vmap(paged_gather, in_axes=(1, 1, None),
-                          out_axes=0)(pool["k"], pool["v"], prefix_tbl)
+        gk, gv = paged_gather(pool["k"], pool["v"], prefix_tbl, heads,
+                              layer=jnp.arange(cfg.n_layers)[:, None, None])
 
         def body(x, inp):
             lp, pk, pv = inp
@@ -449,36 +458,64 @@ class DecoderLM(BaseModel):
         x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], gk, gv))
         x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
         logits = self._unembed(params, x[:, -1])
-
-        def per_layer(kp, vp, kl, vl):
-            return paged_scatter_pages(kp, vp, scatter_tbl, kl, vl)
-
-        nk, nv = jax.vmap(per_layer, in_axes=(1, 1, 0, 0),
-                          out_axes=(1, 1))(pool["k"], pool["v"], ks, vs)
+        nk, nv = paged_scatter_pages(pool["k"], pool["v"], scatter_tbl,
+                                     ks, vs)
         return logits, {"k": nk, "v": nv}
 
     def paged_decode(self, params, pool, table, pos, t, batch, *, page):
-        """Gather the dense per-row view through the page table, run the
-        ordinary decode on it, scatter the newly written slot back.
-        Returns (logits, pool', pos', t')."""
-        nlp = table.shape[1]
-        C = nlp * page
-        gk, gv = jax.vmap(paged_gather, in_axes=(1, 1, None),
-                          out_axes=0)(pool["k"], pool["v"], table)
-        logits, nc = self.decode(
-            params, {"k": gk, "v": gv, "pos": pos, "t": t}, batch)
-        slot = t % C
+        """One decode tick on the pool in place, layer by layer: the
+        layer scan carries the pool, gathers only layer l's pages for
+        each row, runs the ring decode's layer body on that view and
+        writes the one new slot per row back into the carried pool.
+        Nothing layer-stacked of cache size is built, and the pool is
+        never relaid out. Returns (logits, pool', pos', t')."""
+        cfg = self.cfg
+        heads = (cfg.n_kv_heads, cfg.dh)
+        x = self._embed(params, {"tokens": batch["token"]})
+        B, n = table.shape
+        slot = t % (n * page)
         tbl_col = jnp.take(table, slot // page, axis=1)
-        off = slot % page
-        k1 = jax.lax.dynamic_slice_in_dim(nc["k"], slot, 1, axis=2)
-        v1 = jax.lax.dynamic_slice_in_dim(nc["v"], slot, 1, axis=2)
+        kv_pos = jax.lax.dynamic_update_slice(pos, t[None], (slot,))
+        # Selects, not dynamic_update_slice: under the bank's vmap each
+        # expert has its own slot, which would turn an update into a
+        # scatter that the TPU runs as a loop over experts (and over
+        # rows, for a KV-wide window inside a page row). The selected
+        # values are the same, so paged logits stay bitwise the ring's.
+        at_slot = (jnp.arange(n * page) == slot)[:, None, None]
+        W = cfg.n_kv_heads * cfg.dh
+        in_row = jnp.arange(page * W) // W == slot % page
 
-        def per_layer(kp, vp, kl, vl):
-            return paged_append(kp, vp, tbl_col, off, kl, vl)
+        def body(carry, inp):
+            x, kp, vp = carry
+            lp, l = inp
+            ck, cv = paged_gather(kp, vp, table, heads, layer=l)
+            new = {}                  # the layer body's k1, v1, kept here
 
-        nk, nv = jax.vmap(per_layer, in_axes=(1, 1, 0, 0),
-                          out_axes=(1, 1))(pool["k"], pool["v"], k1, v1)
-        return logits, {"k": nk, "v": nv}, nc["pos"], nc["t"]
+            def update(k1, v1):
+                new["k"], new["v"] = k1.astype(ck.dtype), v1.astype(cv.dtype)
+                nk = jnp.where(at_slot, new["k"], ck)
+                nv = jnp.where(at_slot, new["v"], cv)
+                return nk, nv, kv_pos
+
+            x, _ = _layer_decode(x, lp, {"update": update}, cfg, t)
+
+            def write(pages, x1):
+                # the slot's whole page row goes back, its other slots
+                # unchanged: a written page belongs to one row (tail
+                # pages are the row's own, wrapped shared pages are
+                # copied first), padding rows all write the trash page
+                row = jnp.where(in_row, jnp.tile(x1.reshape(B, -1), page),
+                                pages[l, tbl_col])
+                return pages.at[l, tbl_col].set(row)
+
+            return (x, write(kp, new["k"]), write(vp, new["v"])), None
+
+        (x, kp, vp), _ = jax.lax.scan(
+            body, (x, pool["k"], pool["v"]),
+            (params["layers"], jnp.arange(cfg.n_layers)))
+        x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        logits = self._unembed(params, x[:, 0])
+        return logits, {"k": kp, "v": vp}, kv_pos, t + 1
 
     # ------------------------------------------------------------------
     def input_shapes(self, sc):

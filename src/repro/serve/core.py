@@ -373,7 +373,7 @@ class EngineCore:
         self.kv_layout = kv_layout
         self.pool: Optional[PagePool] = None
         self.prefix_cache: Optional[PrefixCache] = None
-        self.kv_pool = None                  # {k, v}: (E, P1, L, page, ...)
+        self.kv_pool = None                  # {k, v}: (E, L, P1, ...)
         if kv_layout == "paged":
             if not model.supports_paged_kv:
                 raise ValueError(
@@ -699,9 +699,16 @@ class EngineCore:
         power-of-two ladder (padding copies trash -> trash, a no-op),
         so the wrapper count stays bounded under arbitrary traffic."""
         if m not in self._copy_fns:
+            # pool planes are layer-major, (E, L, P1, ...): a page is
+            # copied in every layer, one (layer, page) row per index —
+            # batching over the layer axis would relay out the pool
             def fn(pool, es, srcs, dsts):
-                return {k: v.at[es, dsts].set(v[es, srcs])
-                        for k, v in pool.items()}
+                out = {}
+                for k, v in pool.items():
+                    ls = jnp.arange(v.shape[1])
+                    out[k] = v.at[es[:, None], ls, dsts[:, None]].set(
+                        v[es[:, None], ls, srcs[:, None]])
+                return out
             s = self._bank_sharding()
             if s is not None:
                 jitted = jax.jit(fn, in_shardings=(s, None, None, None),

@@ -1,18 +1,21 @@
 """Paged KV cache tests: pool allocator invariants, prefix-cache
 refcounting, paged-vs-ring token identity on the traffic grids,
-shared-prefix prefill savings, copy-on-write under ring wrap, and
-clean backpressure on pool exhaustion."""
+shared-prefix prefill savings, copy-on-write under ring wrap, clean
+backpressure on pool exhaustion, and the decode tick itself: one tick
+against the ring decode, and the structure of its lowered program."""
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend.mlir import ir
 
 from repro.configs import get_config
 from repro.core import ExpertRegistry, build_matcher, train_bank
 from repro.data import load_benchmark
 from repro.models import build_model
-from repro.serve import (ExpertEngine, PagePool, PagePoolExhausted,
-                         PrefixCache, Request, RoutedServer, hash_chain,
-                         plan_placement)
+from repro.serve import (EngineCore, ExpertEngine, PagePool,
+                         PagePoolExhausted, PrefixCache, Request,
+                         RoutedServer, hash_chain, plan_placement)
 
 from _prop import given, settings, strategies as st
 
@@ -464,3 +467,146 @@ def test_paged_banked_matches_ring_per_engine(matcher, bench,
                                       err_msg=str(a.uid))
     assert plan.shards[0].bank.stats.prefix_dup_rows >= 1
     plan.shards[0].bank.core.pool.check()
+
+
+# -- the decode tick, model level ---------------------------------------------
+
+
+def _tick_core(n_experts, n_layers=2):
+    """A paged engine of ``n_experts`` stacked experts (1: the unbanked
+    engine ``ExpertEngine`` builds; 3: a bank) with its decode ladder."""
+    cfg = get_config("smollm-135m").reduced(name="paged-tick",
+                                            n_layers=n_layers)
+    model = build_model(cfg)
+    params = [model.init(jax.random.PRNGKey(s)) for s in range(n_experts)]
+    return EngineCore(model, params, max_len=64, batch_buckets=(4,),
+                      kv_layout="paged")
+
+
+@pytest.mark.parametrize("n_experts", [1, 3])
+def test_paged_decode_tick_matches_ring_decode_in_place(n_experts):
+    """One tick of the engine's paged decode (vmapped over the experts,
+    as served) against the ring decode on the same rows: logits are
+    bitwise equal, and the pool afterwards equals the pool before with
+    exactly the one new slot per real row per layer written — the ring
+    decode's K/V at that slot. The padding row writes only the trash
+    page; every other page, the row-tile padding included, is
+    untouched."""
+    core = _tick_core(n_experts)
+    model, E, page, n = core.model, core.n_experts, core.page, core.n_logical
+    cfg = model.cfg
+    heads, W = (cfg.n_kv_heads, cfg.dh), cfg.n_kv_heads * cfg.dh
+    Bb, C, trash = 4, n * page, core.pool.trash
+    rng = np.random.default_rng(7 + E)
+    shape = core.kv_pool["k"].shape                  # (E, L, rows, R)
+    before = {k: rng.standard_normal(shape).astype(np.float32)
+              for k in ("k", "v")}
+    table = np.full((E, Bb, n), trash, np.int32)     # last row: padding
+    for e in range(E):
+        table[e, :Bb - 1] = rng.permutation(trash)[:(Bb - 1) * n].reshape(
+            Bb - 1, n)
+    t = (C // 2 + 3 + np.arange(E)).astype(np.int32)  # mid-page slots
+    ar = np.arange(C)
+    pos = np.where(ar[None] < t[:, None], ar[None], -1).astype(np.int32)
+    tok = rng.integers(0, cfg.vocab_size, (E, Bb, 1)).astype(np.int32)
+
+    # the ring decode on each row's dense view, read from the pool here
+    views = {k: np.stack([before[k][e][:, table[e]].reshape(
+        cfg.n_layers, Bb, C, *heads) for e in range(E)]) for k in before}
+    ring_logits, ring = jax.jit(jax.vmap(model.decode))(
+        core.params, {"k": views["k"], "v": views["v"], "pos": pos, "t": t},
+        {"token": tok})
+
+    logits, pool, pos2, t2 = core._decode_fn(Bb)(
+        core.params, {k: jnp.asarray(v) for k, v in before.items()},
+        jnp.asarray(table), jnp.asarray(pos), jnp.asarray(t),
+        {"token": jnp.asarray(tok)})
+    np.testing.assert_array_equal(np.asarray(logits),
+                                  np.asarray(ring_logits))
+    np.testing.assert_array_equal(np.asarray(pos2), np.asarray(ring["pos"]))
+    np.testing.assert_array_equal(np.asarray(t2), t + 1)
+    for k in ("k", "v"):
+        got, want = np.asarray(pool[k]), before[k].copy()
+        new = np.asarray(ring[k])                    # (E, L, B, C, KV, dh)
+        for e in range(E):
+            slot = t[e] % C
+            pg, off = table[e, :Bb - 1, slot // page], slot % page
+            want[e][:, pg, off * W:(off + 1) * W] = new[e][
+                :, :Bb - 1, slot].reshape(cfg.n_layers, Bb - 1, W)
+        keep = np.arange(shape[2]) != trash
+        np.testing.assert_array_equal(got[:, :, keep], want[:, :, keep])
+        written = got[:, :, keep] != before[k][:, :, keep]
+        assert written.sum() == E * cfg.n_layers * (Bb - 1) * W, k
+
+
+def _hlo_ops(op):
+    """Every operation nested in ``op``'s regions, depth first."""
+    for region in op.regions:
+        for block in region.blocks:
+            for inner in block.operations:
+                yield inner
+                yield from _hlo_ops(inner)
+
+
+def _dims(value):
+    t = value.type
+    return tuple(t.shape) if isinstance(t, ir.RankedTensorType) else ()
+
+
+def test_paged_decode_lowering_moves_no_kv():
+    """The lowered decode tick of a bank keeps its KV pool in place:
+    the only operations that yield a pool-shaped value are the layer
+    loop that carries it, the in-place slot scatter and the call of the
+    loop body, and no value is one layer's plane of it, so the pool is
+    never copied, sliced out or relaid out (the donated input aliases
+    the output, checked by H001); no value is
+    a layer-stacked view (L, B, C, KV, dh) or its page rows; and no
+    transpose moves KV. Transposes of the bank's weights (the vmapped
+    layer scan puts the layer axis before the expert axis) are outside
+    this path and allowed; any other transpose must be smaller than one
+    layer's KV view."""
+    core = _tick_core(3, n_layers=5)
+    cfg = core.model.cfg
+    E, L, Bb, n, page = 3, cfg.n_layers, 4, core.n_logical, core.page
+    C, KV, dh = n * page, cfg.n_kv_heads, cfg.dh
+    S = jax.ShapeDtypeStruct
+    i32 = jnp.int32
+    p_av = jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype),
+                                  core.params)
+    pool_av = jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype),
+                                     core.kv_pool)
+    lowered = core._decode_fn(Bb).lower(
+        p_av, pool_av, S((E, Bb, n), i32), S((E, C), i32), S((E,), i32),
+        {"token": S((E, Bb, 1), i32)})
+    pool_dims = tuple(pool_av["k"].shape)
+    plane = pool_dims[:1] + pool_dims[2:]            # one layer's pages
+    weights = {tuple(x.shape) for x in jax.tree_util.tree_leaves(p_av)}
+    view = E * Bb * C * KV * dh
+    stacked = [sorted(d) for d in ((L, Bb, C, KV, dh),
+                                   (L, Bb, n, page * KV * dh))]
+
+    def holds(dims, sub):
+        rest = list(dims)
+        for d in sub:
+            if d not in rest:
+                return False
+            rest.remove(d)
+        return True
+
+    module = lowered.compiler_ir("stablehlo")
+    makers, n_scatter = set(), 0
+    for op in _hlo_ops(module.operation):
+        name = op.operation.name
+        for r in op.results:
+            dims = _dims(r)
+            assert not any(holds(dims, s) for s in stacked), (name, dims)
+            assert dims != plane, name
+            if dims == pool_dims:
+                makers.add(name)
+                n_scatter += name == "stablehlo.scatter"
+        if name == "stablehlo.transpose":
+            src = _dims(op.operands[0])
+            assert src in weights or int(np.prod(src)) < view, src
+    assert makers == {"stablehlo.while", "stablehlo.scatter",
+                      "func.call"}, makers
+    assert n_scatter == 2                            # K and V, per layer
