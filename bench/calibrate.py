@@ -29,7 +29,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
 def readings_for_seed(cell, seed: int, seconds: float, compiles,
                       t_start: float):
     from bench import correct, harness, traffic
-    sys_ = harness.build(cell.config, cell.traffic, seed, t_start)
+    sys_ = harness.build(cell, seed, t_start)
     harness.warm(sys_)
     offers = traffic.offered(cell.traffic, seed, seconds, sys_.arch.vocab,
                              sys_.clients)

@@ -14,8 +14,9 @@ on what the timed path itself returned:
   chose the reference's expert (or one tied with it within rounding).
 - engine: a sample drawn from the seed of the answered requests, the
   longest among them, with every served token teacher-forced through
-  the f32 reference over the same zero-padded prompt the engine
-  prefilled. ``logit_gap`` is the largest amount by which a served
+  the f32 reference of the cell's model family (``served_gaps`` of
+  ``bench/families/<family>.py``) over the same zero-padded prompt the
+  engine prefilled. ``logit_gap`` is the largest amount by which a served
   token's reference logit lies below the reference's best logit at its
   position. ``short_responses`` counts sampled responses with fewer
   tokens than asked for.
@@ -34,7 +35,7 @@ from typing import Any, Dict, List
 import jax
 import numpy as np
 
-from . import lm_ref, matcher_ref, weights
+from . import matcher_ref, weights
 
 
 @dataclasses.dataclass
@@ -101,11 +102,10 @@ def _router(sys_, got, control: bool) -> Dict[str, float]:
 
 def _engine(sys_, samples: List[Sample], control: bool
             ) -> Dict[str, float]:
-    cfg, a = sys_.cfg, sys_.arch
+    cfg, fam, a = sys_.cfg, sys_.family, sys_.arch
     n_check = int(cfg["check"]["responses"])
     width = sys_.geometry.max_len
     t_max = n_check * int(sys_.mix["max_new"]["max"])
-    eps, theta = float(cfg["rms_norm_eps"]), float(cfg["rope_theta"])
     short = sum(len(s.served) != s.max_new for s in samples)
     worst, exact, checked = 0.0, 0, 0
     for e in sorted({s.expert for s in samples}):
@@ -119,9 +119,9 @@ def _engine(sys_, samples: List[Sample], control: bool
             sb = s.padded_len
             seqs[r, sb:sb + len(s.served) - 1] = s.served[:-1]
             spans.append((r, sb - 1, s.served))
-        params = weights.make_expert(weights.expert_key(sys_.seed, e), a)
-        gap, hit = lm_ref.served_gaps(params, a, eps, theta, seqs, spans,
-                                      pad_to=t_max, control=control)
+        params = fam.make_expert(weights.expert_key(sys_.seed, e), a)
+        gap, hit = fam.served_gaps(params, a, cfg, seqs, spans,
+                                   pad_to=t_max, control=control)
         del params
         worst = max(worst, float(np.max(gap, initial=0.0)))
         exact += int(np.sum(hit))

@@ -11,7 +11,6 @@ import numpy as np
 
 from . import (correct, fingerprints, harness, spec, stats, traffic,
                xtrace)
-from .flops import Arch
 from .harness import log
 
 TRACE_MAX_S = 4.0
@@ -28,8 +27,11 @@ class Served:
 @dataclasses.dataclass
 class View:
     """What a per-layer metric reader may read. ``counters`` are over
-    the whole window, ``trace_counters`` over the profiled seconds."""
-    arch: Arch
+    the whole window, ``trace_counters`` over the profiled seconds.
+    ``family`` is the cell's family module (its work counts), ``arch``
+    its sizes."""
+    family: Any
+    arch: Any
     experts: int                  # experts the matcher scores
     centroids: int                # class centroids per expert
     experts_per_dispatch: int
@@ -89,7 +91,7 @@ def run(cell: spec.Cell, seed: int, seconds: float,
         f"{cell.name}: {cell.config['name']} under "
         f"{ {k: v for k, v in cell.traffic.items() if k != 'server'} }")
 
-    sys_ = harness.build(cell.config, cell.traffic, seed, t_start)
+    sys_ = harness.build(cell, seed, t_start)
     harness.warm(sys_)
     offers = traffic.offered(cell.traffic, seed, seconds,
                              sys_.arch.vocab, sys_.clients)
@@ -125,7 +127,8 @@ def run(cell: spec.Cell, seed: int, seconds: float,
                          int(backend.pad_shape(1, len(o.prompt))[1]),
                          len(win.responses[o.uid].tokens))
                   for o in offers if o.uid in win.responses]
-        view = View(arch=sys_.arch, experts=len(sys_.names),
+        view = View(family=sys_.family, arch=sys_.arch,
+                    experts=len(sys_.names),
                     centroids=max(fingerprints.SPECS[n][0]
                                   for n in sys_.names),
                     experts_per_dispatch=(

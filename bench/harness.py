@@ -2,13 +2,14 @@
 
 Set-up builds the system under test the way a deployment would: the
 benchmark makes the client datasets, trains the matcher's autoencoders
-and makes every expert's weights from the seed, then hands them to the
-program's own entry points (``build_matcher``, ``ExpertEngine``,
-``plan_placement``, ``RoutedServer``). Warm-up drives every shape the
-cell's traffic can reach through the same server. The window is an
-open loop over ``RoutedServer.submit`` / ``RoutedServer.step`` on the
-wall clock. What the window produced is kept for the check, which runs
-once the server is gone.
+and makes every expert's weights from the seed (through the cell's model
+family, ``bench/families/``), then hands them to the program's own
+entry points (``build_matcher``, ``ExpertEngine``, ``plan_placement``,
+``RoutedServer``). Warm-up drives every shape the cell's traffic can
+reach through the same server. The window is an open loop over
+``RoutedServer.submit`` / ``RoutedServer.step`` on the wall clock. What
+the window produced is kept for the check, which runs once the server
+is gone.
 """
 from __future__ import annotations
 
@@ -24,8 +25,7 @@ import jax
 import numpy as np
 
 from . import fingerprints, matcher_ref, traffic, weights
-from .flops import Arch
-from .spec import ROOT
+from .spec import ROOT, Cell
 
 sys.path.insert(0, os.path.join(ROOT, "src"))
 
@@ -33,7 +33,6 @@ from repro.core import ExpertRegistry, MatcherConfig, build_matcher  # noqa: E40
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_expert_mesh  # noqa: E402
 from repro.models import build_model  # noqa: E402
-from repro.models.common import ArchConfig  # noqa: E402
 from repro.obs.trace import Tracer  # noqa: E402
 from repro.serve import (ExpertEngine, Request, RoutedServer,  # noqa: E402
                          plan_placement)
@@ -86,24 +85,6 @@ class CompileLog:
             self.cache[event.rsplit("/", 1)[-1]] += 1
 
 
-def program_arch(cfg: Dict[str, Any]) -> ArchConfig:
-    """The program's config for a configuration file's sizes."""
-    return ArchConfig(
-        name=cfg["name"], family="dense",
-        n_layers=int(cfg["num_hidden_layers"]),
-        d_model=int(cfg["hidden_size"]),
-        n_heads=int(cfg["num_attention_heads"]),
-        n_kv_heads=int(cfg["num_key_value_heads"]),
-        head_dim=int(cfg["head_dim"]),
-        d_ff=int(cfg["intermediate_size"]),
-        vocab_size=int(cfg["vocab_size"]),
-        qkv_bias=bool(cfg["attention_bias"]),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        rope_theta=float(cfg["rope_theta"]),
-        norm_eps=float(cfg["rms_norm_eps"]),
-        param_dtype=cfg["torch_dtype"], compute_dtype=cfg["torch_dtype"])
-
-
 def _round_up(n: int, m: int) -> int:
     return -(-int(n) // m) * m
 
@@ -141,7 +122,8 @@ class System:
     cfg: Dict[str, Any]
     mix: Dict[str, Any]
     seed: int
-    arch: Arch
+    family: Any                       # the config's family module
+    arch: Any                         # family.Arch of the config
     geometry: Geometry
     names: List[str]
     clients: List[np.ndarray]         # per dataset: client A rows
@@ -156,12 +138,12 @@ def expert_names(cfg: Dict[str, Any]) -> List[str]:
     return list(cfg["deployment"]["datasets"])
 
 
-def build(cfg: Dict[str, Any], mix: Dict[str, Any], seed: int,
-          t_start: float) -> System:
+def build(cell: Cell, seed: int, t_start: float) -> System:
     """Set-up, one part per line of the log."""
+    cfg, mix, fam = cell.config, cell.traffic, cell.family
     parts: Dict[str, float] = {}
     names = expert_names(cfg)
-    arch = Arch.from_config(cfg)
+    arch = fam.Arch.from_config(cfg)
     geo = Geometry.of(mix)
 
     t = time.perf_counter()
@@ -182,10 +164,10 @@ def build(cfg: Dict[str, Any], mix: Dict[str, Any], seed: int,
         f"{AE_EPOCHS} epochs ({parts['ae_training']:.3f} s)")
 
     t = time.perf_counter()
-    model = build_model(program_arch(cfg))
+    model = build_model(fam.program_arch(cfg))
     registry = ExpertRegistry()
     for e, name in enumerate(names):
-        dev = weights.make_expert(weights.expert_key(seed, e), arch)
+        dev = fam.make_expert(weights.expert_key(seed, e), arch)
         # the engine keeps the caller's unstacked tree beside its own
         # stacked copy: hand it a host copy, so the chip holds one
         host = jax.device_get(dev)
@@ -216,8 +198,8 @@ def build(cfg: Dict[str, Any], mix: Dict[str, Any], seed: int,
         f"({parts['placement']:.3f} s); bytes in use "
         f"{_mem('bytes_in_use')}, peak so far {_mem('peak_bytes_in_use')}")
 
-    return System(cfg=cfg, mix=mix, seed=seed, arch=arch, geometry=geo,
-                  names=names,
+    return System(cfg=cfg, mix=mix, seed=seed, family=fam, arch=arch,
+                  geometry=geo, names=names,
                   clients=[data[n]["client_a"][0] for n in names],
                   spare=[data[n]["client_b"][0] for n in names],
                   ae_params=ae_params, ae_state=ae_state, server=server,

@@ -1,54 +1,46 @@
-"""Plain reference of a dense GQA decoder (Llama / Qwen2 layout).
+"""Parts of the plain references that every decoder family shares.
 
-Written from the published description, in float32 at ``highest``
-matmul precision, with no cache, batching trick or kernel: token
-embedding; per layer RMSNorm, q/k/v projections (with bias where the
-configuration has it), rotary embedding on the two halves of each head
-(the Hugging Face ``rotate_half`` form), causal grouped-query attention,
-output projection and residual, RMSNorm, SwiGLU feed-forward and
-residual; final RMSNorm and the vocabulary head (the embedding itself
-where tied). The layers run one at a time in a scan, each upcast from
-the served bf16 weights only while it runs, and the head runs over
-vocabulary blocks, so a 14B-wide expert fits beside its own bf16 copy.
-
-``fp8=True`` is the control: the same pass with both operands of every
-projection, feed-forward and head matmul rounded to float8 e4m3 with a
-per-tensor scale, accumulation in float32. It is the step below the
-bf16 the configurations state.
+A family module (``bench/families/``) writes its own layers from the
+published description and builds them from these: float32 matmuls at
+``highest`` precision, with both operands rounded to float8 e4m3 (a
+per-tensor scale, accumulation in float32) for the control, the step
+below the bf16 the configurations state; RMSNorm; rotary embedding on
+the two halves of each head (the Hugging Face ``rotate_half`` form); the
+vocabulary head over blocks of the vocabulary, so a wide head fits
+beside its own bf16 copy; and the teacher-forced check of served tokens
+(``served_gaps``), given the family's final-normed hidden states.
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .flops import Arch
-
 HI = jax.lax.Precision.HIGHEST
 E4M3_MAX = 448.0
 
 
-def _q8(x):
+def q8(x):
     s = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / E4M3_MAX
     return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
 
 
-def _mm(x, w, fp8: bool):
+def mm(x, w, fp8: bool):
     w = w.astype(jnp.float32)
     if fp8:
-        x, w = _q8(x), _q8(w)
+        x, w = q8(x), q8(w)
     return jnp.matmul(x, w, precision=HI)
 
 
-def _rms(x, scale, eps):
+def rms(x, scale, eps):
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """x: (n, S, heads, dh); rotate the halves of each head."""
     dh = x.shape[-1]
     inv = 1.0 / theta ** (jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
@@ -58,61 +50,22 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _layer(x, lp, a: Arch, eps: float, theta: float, fp8: bool):
-    n, S, _ = x.shape
-    H, KV, dh = a.heads, a.kv_heads, a.head_dim
-    pos = jnp.arange(S)
-    h = _rms(x, lp["ln1"], eps)
-    q, k, v = (_mm(h, lp[w], fp8) for w in ("wq", "wk", "wv"))
-    if a.qkv_bias:
-        q = q + lp["bq"].astype(jnp.float32)
-        k = k + lp["bk"].astype(jnp.float32)
-        v = v + lp["bv"].astype(jnp.float32)
-    q = _rope(q.reshape(n, S, H, dh), pos, theta)
-    k = _rope(k.reshape(n, S, KV, dh), pos, theta)
-    v = v.reshape(n, S, KV, dh)
-    g = H // KV
-    q = q.reshape(n, S, KV, g, dh)
-    s = jnp.einsum("nqkgd,nskd->nkgqs", q, k, precision=HI) / np.sqrt(dh)
-    causal = pos[None, :] <= pos[:, None]
-    s = jnp.where(causal, s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("nkgqs,nskd->nqkgd", p, v, precision=HI)
-    x = x + _mm(o.reshape(n, S, H * dh), lp["wo"], fp8)
-    h = _rms(x, lp["ln2"], eps)
-    m = lp["mlp"]
-    y = jax.nn.silu(_mm(h, m["w_gate"], fp8)) * _mm(h, m["w_up"], fp8)
-    return x + _mm(y, m["w_down"], fp8)
-
-
-@functools.partial(jax.jit, static_argnames=("a", "eps", "theta", "fp8"))
-def hidden_at(params, tokens, rows, cols, *, a: Arch, eps: float,
-              theta: float, fp8: bool):
-    """Final-normed hidden states at (rows[i], cols[i]) of a causal pass
-    over ``tokens`` (n, S): (T, hidden) float32."""
-    x = params["embed"][tokens].astype(jnp.float32)
-
-    def body(x, lp):
-        return _layer(x, lp, a, eps, theta, fp8), None
-
-    x, _ = jax.lax.scan(body, x, params["layers"])
-    return _rms(x[rows, cols], params["ln_f"], eps)
-
-
-@functools.partial(jax.jit, static_argnames=("a", "blocks", "fp8"))
-def head_stats(params, h, picks, *, a: Arch, blocks: int, fp8: bool):
-    """Over the vocabulary in ``blocks`` blocks: the largest logit of
-    each row, its index, and the logits at ``picks`` (T, k)."""
-    w = params["embed"].T if a.tied else params["unembed"]
+@functools.partial(jax.jit, static_argnames=("tied", "blocks", "fp8"))
+def head_stats(params, h, picks, *, tied: bool, blocks: int, fp8: bool):
+    """Over the vocabulary in ``blocks`` blocks of the head (the
+    embedding's transpose where ``tied``, else ``params["unembed"]``):
+    the largest logit of each row, its index, and the logits at
+    ``picks`` (T, k)."""
+    w = params["embed"].T if tied else params["unembed"]
     T, V = h.shape[0], w.shape[1]
     bw = V // blocks
-    hq = _q8(h) if fp8 else h
+    hq = q8(h) if fp8 else h
 
     def body(carry, b):
         best, arg, got = carry
         wb = jax.lax.dynamic_slice_in_dim(w, b * bw, bw, axis=1)
         wb = wb.astype(jnp.float32)
-        lg = jnp.matmul(hq, _q8(wb) if fp8 else wb, precision=HI)
+        lg = jnp.matmul(hq, q8(wb) if fp8 else wb, precision=HI)
         bmax, barg = jnp.max(lg, -1), jnp.argmax(lg, -1) + b * bw
         arg = jnp.where(bmax > best, barg, arg)
         best = jnp.maximum(best, bmax)
@@ -135,20 +88,23 @@ def vocab_blocks(vocab: int) -> int:
     return 1
 
 
-def served_gaps(params, a: Arch, eps: float, theta: float,
-                seqs: np.ndarray, spans, *, pad_to: int,
+def served_gaps(hidden: Callable, params, seqs: np.ndarray, spans, *,
+                tied: bool, vocab: int, pad_to: int,
                 control: bool = False) -> Tuple[np.ndarray, np.ndarray]:
     """Teacher-forced check of served tokens.
 
-    ``seqs`` (n, S) holds each padded prompt followed by its served
-    tokens; ``spans`` lists (row, first position, served tokens) with
-    the first served token predicted at that position. Returns, per
-    served token, the f32 reference's gap between its best logit and
-    the served token's logit, and 1 where the served token is the f32
-    argmax. With ``control`` the gap is that of the token the fp8 pass
-    puts first, in place of the served one. The token list is padded to
-    ``pad_to`` entries, so one cell always compiles the same shapes;
-    only the first entries, one per served token, are returned."""
+    ``hidden(tokens, rows, cols, fp8)`` is the family's reference: the
+    final-normed hidden states at (rows[i], cols[i]) of a causal pass
+    over ``tokens`` (n, S). ``seqs`` (n, S) holds each padded prompt
+    followed by its served tokens; ``spans`` lists (row, first position,
+    served tokens) with the first served token predicted at that
+    position. Returns, per served token, the f32 reference's gap between
+    its best logit and the served token's logit, and 1 where the served
+    token is the f32 argmax. With ``control`` the gap is that of the
+    token the fp8 pass puts first, in place of the served one. The token
+    list is padded to ``pad_to`` entries, so one cell always compiles
+    the same shapes; only the first entries, one per served token, are
+    returned."""
     rows, cols, toks = [], [], []
     for r, p0, served in spans:
         for j, t in enumerate(served):
@@ -162,18 +118,16 @@ def served_gaps(params, a: Arch, eps: float, theta: float,
     rows = np.asarray(rows + pad, np.int32)
     cols = np.asarray(cols + pad, np.int32)
     toks = np.asarray(toks + pad, np.int32)
-    nb = vocab_blocks(a.vocab)
+    nb = vocab_blocks(vocab)
     tok_dev = jnp.asarray(seqs, jnp.int32)
-    h = hidden_at(params, tok_dev, rows, cols, a=a, eps=eps, theta=theta,
-                  fp8=False)
+    h = hidden(tok_dev, rows, cols, False)
     if control:
-        hq = hidden_at(params, tok_dev, rows, cols, a=a, eps=eps,
-                       theta=theta, fp8=True)
+        hq = hidden(tok_dev, rows, cols, True)
         _, toks_q, _ = head_stats(params, hq, jnp.zeros((len(rows), 1),
                                                         jnp.int32),
-                                  a=a, blocks=nb, fp8=True)
+                                  tied=tied, blocks=nb, fp8=True)
         toks = np.asarray(toks_q, np.int32)
     best, arg, got = head_stats(params, h, jnp.asarray(toks)[:, None],
-                                a=a, blocks=nb, fp8=False)
+                                tied=tied, blocks=nb, fp8=False)
     gap = np.asarray(best) - np.asarray(got)[:, 0]
     return gap[:n], (np.asarray(arg) == toks)[:n].astype(np.int32)

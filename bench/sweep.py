@@ -42,7 +42,7 @@ def main(argv=None) -> int:
         print("sweep: needs a TPU", file=sys.stderr)
         return 2
     compiles = harness.start()
-    sys_ = harness.build(cell.config, cell.traffic, args.seed, T_START)
+    sys_ = harness.build(cell, args.seed, T_START)
     harness.warm(sys_)
     for rate in (float(r) for r in args.rates.split(",")):
         mix = copy.deepcopy(cell.traffic)

@@ -3,12 +3,12 @@ worked out by hand."""
 import pytest
 
 from bench import flops, peaks, stats
-from bench.flops import Arch
+from bench.families import dense
 
-SMOLLM = Arch(layers=30, hidden=576, heads=9, kv_heads=3, head_dim=64,
-              ffn=1536, vocab=49152, tied=True, qkv_bias=False)
-QWEN4 = Arch(layers=4, hidden=5120, heads=40, kv_heads=8, head_dim=128,
-             ffn=13824, vocab=152064, tied=False, qkv_bias=True)
+SMOLLM = dense.Arch(layers=30, hidden=576, heads=9, kv_heads=3, head_dim=64,
+                    ffn=1536, vocab=49152, tied=True, qkv_bias=False)
+QWEN4 = dense.Arch(layers=4, hidden=5120, heads=40, kv_heads=8, head_dim=128,
+                   ffn=13824, vocab=152064, tied=False, qkv_bias=True)
 
 
 def test_window_with_a_stall():
@@ -49,23 +49,23 @@ def test_unknown_device_kind_is_an_error():
 
 def test_smollm_bank_by_hand():
     # per layer: q 576x576, k and v 576x192, o 576x576, three 576x1536
-    assert flops.layer_matmul_params(SMOLLM) == 3_538_944
+    assert dense.layer_matmul_params(SMOLLM) == 3_538_944
     params = 30 * 3_538_944 + 49152 * 576          # 134,475,264
-    assert flops.expert_param_bytes(SMOLLM) == \
+    assert dense.expert_param_bytes(SMOLLM) == \
         2 * params + 30 * 2 * 576 * 4 + 576 * 4
-    assert 6 * flops.expert_param_bytes(SMOLLM) == pytest.approx(1.61e9,
+    assert 6 * dense.expert_param_bytes(SMOLLM) == pytest.approx(1.61e9,
                                                                  rel=0.01)
-    assert flops.kv_bytes_per_token(SMOLLM) == 23_040
+    assert dense.kv_bytes_per_token(SMOLLM) == 23_040
 
 
 def test_qwen_stage_by_hand():
-    assert flops.expert_param_bytes(QWEN4) == pytest.approx(5.32e9,
+    assert dense.expert_param_bytes(QWEN4) == pytest.approx(5.32e9,
                                                             rel=0.01)
-    streamed = flops.decode_streamed_bytes(QWEN4)
+    streamed = dense.weight_bytes_read(QWEN4, 1)
     assert streamed == pytest.approx(3.76e9, rel=0.01)
-    assert flops.head_param_bytes(QWEN4) / streamed == pytest.approx(
+    assert dense.head_param_bytes(QWEN4) / streamed == pytest.approx(
         0.41, abs=0.01)
-    assert flops.kv_bytes_per_token(QWEN4) == 16_384
+    assert dense.kv_bytes_per_token(QWEN4) == 16_384
     # 4.6 ms per expert per decode step at 819 GB/s
     assert streamed / 819e9 == pytest.approx(4.6e-3, rel=0.01)
 
@@ -74,8 +74,8 @@ def test_step_flops_by_hand():
     lin = 2 * 30 * 3_538_944
     head = 2 * 576 * 49152
     attn1 = 4 * 30 * 9 * 64
-    assert flops.decode_flops(SMOLLM, 100) == lin + attn1 * 100 + head
-    assert flops.prefill_flops(SMOLLM, 4) == \
+    assert dense.decode_flops(SMOLLM, 100) == lin + attn1 * 100 + head
+    assert dense.prefill_flops(SMOLLM, 4) == \
         4 * lin + attn1 * (4 * 5 / 2) + head
 
 
@@ -102,8 +102,9 @@ def test_served_means():
     class S:
         def __init__(self, p, sb, n):
             self.prompt_len, self.padded_len, self.tokens = p, sb, n
-    pre, keys = flops.served_means(SMOLLM, [S(30, 32, 3), S(60, 64, 1)])
-    assert pre == pytest.approx((flops.prefill_flops(SMOLLM, 30)
-                                 + flops.prefill_flops(SMOLLM, 60)) / 2)
+    pre, keys = flops.served_means(dense, SMOLLM,
+                                   [S(30, 32, 3), S(60, 64, 1)])
+    assert pre == pytest.approx((dense.prefill_flops(SMOLLM, 30)
+                                 + dense.prefill_flops(SMOLLM, 60)) / 2)
     # row 1 decodes 2 tokens seeing 33 and 34 keys; row 2 decodes none
     assert keys == pytest.approx(33.5)

@@ -22,6 +22,7 @@ from bench import correct, driver, harness, spec, traffic
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 PEAKS = {"peak_flops_bf16": 197e12, "hbm_bytes_per_s": 819e9}
+DENSE = spec.family("dense")
 
 
 def _cell():
@@ -32,7 +33,7 @@ def _cell():
     with open(os.path.join(spec.ROOT, "BENCHMARK.json")) as f:
         doc = json.load(f)
     return spec.Cell(name="smollm6.fresh", chips=1, config=cfg,
-                     traffic=mix, end_to_end=doc["end_to_end"],
+                     family=DENSE, traffic=mix, end_to_end=doc["end_to_end"],
                      per_layer=[])
 
 
@@ -52,7 +53,7 @@ def test_sound_run_is_correct():
 def test_control_is_not_correct():
     cell = _cell()
     compiles = harness.CompileLog()
-    sys_ = harness.build(cell.config, cell.traffic, 4, time.perf_counter())
+    sys_ = harness.build(cell, 4, time.perf_counter())
     harness.warm(sys_)
     offers = traffic.offered(cell.traffic, 4, 2.0, sys_.arch.vocab,
                              sys_.clients)

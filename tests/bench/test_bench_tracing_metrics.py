@@ -6,7 +6,7 @@ import pytest
 
 from bench import spec
 from bench.driver import View
-from bench.flops import Arch
+from bench.families import dense
 
 READERS = ("route_wait_ms_per_req", "dispatches_ahead_per_route",
            "enqueue_ms_per_dispatch", "sync_wait_share")
@@ -19,11 +19,11 @@ def _span(sid, name, ts, dur_ms, parent=0, cat="host", **args):
 
 
 def _view(spans):
-    arch = Arch(layers=1, hidden=8, heads=1, kv_heads=1, head_dim=8,
-                ffn=8, vocab=8, tied=True, qkv_bias=False)
-    return View(arch=arch, experts=2, centroids=2, experts_per_dispatch=1,
-                peaks={}, counters={}, trace_counters=None, spans=spans,
-                trace=None, served=[])
+    arch = dense.Arch(layers=1, hidden=8, heads=1, kv_heads=1, head_dim=8,
+                      ffn=8, vocab=8, tied=True, qkv_bias=False)
+    return View(family=dense, arch=arch, experts=2, centroids=2,
+                experts_per_dispatch=1, peaks={}, counters={},
+                trace_counters=None, spans=spans, trace=None, served=[])
 
 
 # two routing calls (2 and 3 rows) with three blocking transfers between
